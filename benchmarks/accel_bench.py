@@ -759,6 +759,9 @@ if __name__ == "__main__":
     ap.add_argument("--tune-json", default="BENCH_tune.json",
                     help="output path for the auto-tuner benchmark")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     if args.tune_only:
         run_tune(json_path=args.tune_json)

@@ -38,6 +38,7 @@ lazily.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Iterator, Optional
 
@@ -206,13 +207,38 @@ def partition_for(tag: str, n: int, m: int, shards: int) -> Optional[str]:
     return "row" if n % shards == 0 else None
 
 
+@functools.partial(jax.jit, static_argnames=("ba", "coding", "per_channel"))
+def _compile_matrices(w: jax.Array, ba: int, coding: Coding,
+                      per_channel: bool):
+    """Quantize + decompose a stack of matrices ``[C, N, M]`` into
+    ``(ws [C, N, BA, M] int8, wq [C, N, M] int16, scale)``.
+
+    One jitted program per shape, mapped over the stack with
+    ``lax.map``: the f32 plane intermediates (``[N, M, BA]``) exist for
+    one matrix at a time, never for the whole stack — a full-width
+    model's images build next to its params on one device.
+    """
+    from repro.core.bpbs import BpbsConfig, weight_planes
+
+    cfg = BpbsConfig(ba=ba, coding=coding)
+
+    def one(wi):
+        qw = quantize(wi.astype(jnp.float32), ba, coding,
+                      axis=1 if per_channel else None)
+        wp = weight_planes(qw.q, cfg)                     # [N, M, BA]
+        return (jnp.transpose(wp, (0, 2, 1)).astype(jnp.int8),
+                qw.q.astype(jnp.int16), qw.scale)
+
+    return jax.lax.map(one, w)
+
+
 def _compile_image(w: jax.Array, spec, path: str,
                    shards: int = 1,
                    partition: Optional[str] = None) -> CimaImage:
     """Quantize + decompose one projection (possibly stacked) into planes.
 
     Applies exactly the per-matrix quantization the on-the-fly backends
-    apply per call (vmapped over stacked copies), so reconstruction at
+    apply per call (mapped over stacked copies), so reconstruction at
     dispatch is bit-identical.  ``partition``/``shards`` only change the
     *accounting* (tiles/segments are per-device shard sizes) and the
     metadata dispatch uses to route through ``shard_map`` — the stored
@@ -220,26 +246,13 @@ def _compile_image(w: jax.Array, spec, path: str,
     """
     lead = w.shape[:-2]
     n, m = int(w.shape[-2]), int(w.shape[-1])
-    cfg = spec.bpbs()
-
-    def one(wi):
-        from repro.core.bpbs import weight_planes
-
-        qw = quantize(wi.astype(jnp.float32), spec.ba, spec.coding,
-                      axis=1 if spec.per_channel else None)
-        wp = weight_planes(qw.q, cfg)                     # [N, M, BA]
-        return (jnp.transpose(wp, (0, 2, 1)).astype(jnp.int8),
-                qw.q.astype(jnp.int16), qw.scale)
-
-    if lead:
-        copies = int(math.prod(lead))
-        ws, wq, scale = jax.vmap(one)(w.reshape((copies,) + w.shape[-2:]))
-        ws = ws.reshape(lead + ws.shape[1:])
-        wq = wq.reshape(lead + wq.shape[1:])
-        scale = scale.reshape(lead + scale.shape[1:])
-    else:
-        copies = 1
-        ws, wq, scale = one(w)
+    copies = int(math.prod(lead)) if lead else 1
+    ws, wq, scale = _compile_matrices(
+        w.reshape((copies,) + w.shape[-2:]), spec.ba, Coding(spec.coding),
+        spec.per_channel)
+    ws = ws.reshape(lead + ws.shape[1:])
+    wq = wq.reshape(lead + wq.shape[1:])
+    scale = scale.reshape(lead + scale.shape[1:])
     devices = shards if partition in ("col", "row") else 1
     n_loc = n // devices if partition == "row" else n
     m_loc = m // devices if partition == "col" else m

@@ -4,7 +4,7 @@ One 65nm chip aligns storage and compute spatially across its 16 banks;
 this module does the same across *devices*: a compiled
 :class:`~repro.accel.program.CimaImage` whose ``partition`` metadata says
 how its bit planes split over the mesh ``"model"`` axis is executed under
-:func:`jax.experimental.shard_map.shard_map`, one per-device tile of the
+:func:`jax.shard_map`, one per-device tile of the
 program per chip (DESIGN.md §9):
 
 * ``"col"`` (column-parallel): every device holds ``m/devices`` output
@@ -55,7 +55,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -234,10 +233,10 @@ def sharded_program_matmul(x: jax.Array, spec, image, mesh,
 
     ndim = qx.q.ndim
     with manual():
-        y = shard_map(
+        y = jax.shard_map(
             body, mesh=mesh,
             in_specs=(_x_spec(ndim, part, lead),) + w_specs,
-            out_specs=_out_spec(ndim, part, lead), check_rep=False,
+            out_specs=_out_spec(ndim, part, lead), check_vma=False,
         )(qx.q, *operands)
     if post is None:
         return rescale(y, qx.scale, image.scale, spec)

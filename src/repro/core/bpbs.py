@@ -207,8 +207,11 @@ def bpbs_matmul_planes(
             key, subkey = jax.random.split(key)
         d_hat = gemm_adc_epilogue(d, nu[..., None, None, None],
                                   float(e - s), cfg, subkey)
-        # near-memory datapath: barrel shift (plane weights) + accumulate
-        y = y + jnp.einsum("...xam,x,a->...m", d_hat, wxv, wav)
+        # near-memory datapath: barrel shift (plane weights) + accumulate.
+        # Exact digital arithmetic: HIGHEST keeps the TPU from rounding
+        # the (integer, up to bank-sized) plane sums to bf16 in the dot.
+        y = y + jnp.einsum("...xam,x,a->...m", d_hat, wxv, wav,
+                           precision=jax.lax.Precision.HIGHEST)
     return y
 
 

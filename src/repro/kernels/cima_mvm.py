@@ -19,6 +19,11 @@ TPU-native mapping of the chip's dataflow (see DESIGN.md §2):
 
 Grid: ``(batch_tiles, column_tiles, banks)`` with the bank dimension
 innermost ("arbitrary" semantics) so output tiles accumulate in place.
+Each input and weight plane arrives as its own dense 2D block, the
+per-bank unmasked counts bank-major, and the plane liveness (plane-skip
+gate) and per-bank ADC full scales as scalar-prefetch operands in SMEM:
+every block obeys the TPU tiling rules, so the kernel compiles with
+``interpret=False`` at any bank count (tests/test_chip_compile.py).
 
 Inputs are int8 bit planes (HBM traffic = 1 byte/plane-element); they are
 cast to bf16 in-kernel for the MXU (values are exactly representable; f32
@@ -33,17 +38,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bpbs import BpbsConfig, gemm_adc_epilogue
-from repro.kernels import _compat
 
 
 def _kernel(
-    xs_ref,     # [bb, BX, bank_n] int8: masked input bit planes
-    ws_ref,     # [bank_n, BA, bm] int8: weight bit planes (bit-parallel)
-    nu_ref,     # [bb, 1] f32: unmasked-row count for this bank
-    fs_ref,     # [1, 1]  f32: ADC full scale for this bank (static gating)
-    *rest,      # fused epilogue: es_ref, pb_ref [1, bm] f32 — then out_ref
+    live_ref,   # SMEM [tiles*banks*BX] i32: is input plane (i, k, kx) live?
+    fs_ref,     # SMEM [banks] f32: ADC full scale per bank (static gating)
+    *refs,      # BX x [bb, bank_n] int8 input planes (masked), BA x
+                # [bank_n, bm] int8 weight planes (bit-parallel), nu [bb, 1]
+                # f32 unmasked rows of this bank, then the fused epilogue's
+                # es_ref, pb_ref [1|bb, bm] f32 — then out_ref
     cfg: BpbsConfig,
     wx: tuple,
     wa: tuple,
@@ -51,27 +57,30 @@ def _kernel(
     act: str = "",
     by_bits: int = 0,
 ):
+    xs_refs, refs = refs[:cfg.bx], refs[cfg.bx:]
+    ws_refs, refs = refs[:cfg.ba], refs[cfg.ba:]
+    nu_ref, rest = refs[0], refs[1:]
     out_ref = rest[-1]  # [bb, bm] f32: recombined output
     fused = len(rest) > 1
-    k = pl.program_id(2)
+    i, k = pl.program_id(0), pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
     nu = nu_ref[...]                                  # [bb, 1]
-    fs_static = fs_ref[0, 0]
+    fs_static = fs_ref[k]
 
     acc = jnp.zeros(out_ref.shape, dtype=jnp.float32)
     for kx in range(cfg.bx):
-        xk = xs_ref[:, kx, :]                         # [bb, bank_n] int8
+        xk = xs_refs[kx][...]                         # [bb, bank_n] int8
 
         def _gemms(xk):
             x = xk.astype(jnp.bfloat16)
             # mixed-signal column evaluations: one MXU pass per plane pair
             return tuple(
                 jax.lax.dot_general(
-                    x, ws_ref[:, ka, :].astype(jnp.bfloat16),
+                    x, ws_refs[ka][...].astype(jnp.bfloat16),
                     (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
@@ -83,9 +92,13 @@ def _kernel(
             # passes are gated off at runtime.  Only the (provably zero)
             # dot products are skipped; the ADC epilogue below still runs
             # on the zeros, keeping the output bit-identical to the dense
-            # path for every coding/precision.
+            # path for every coding/precision.  Liveness of this tile's
+            # (bank, plane) is precomputed into SMEM (prepare step) and
+            # read here as a scalar: the controller knows the mask before
+            # the evaluation fires.
+            live = live_ref[(i * n_banks + k) * cfg.bx + kx]
             ds = jax.lax.cond(
-                jnp.any(xk != 0), _gemms,
+                live != 0, _gemms,
                 lambda _: tuple(jnp.zeros(out_ref.shape, jnp.float32)
                                 for _ in range(cfg.ba)),
                 xk)
@@ -173,12 +186,36 @@ def cima_mvm_planes(
 
     fused = (escale is not None or pbias is not None
              or bool(act) or bool(by_bits))
-    operands = [xs, ws, nu, fs.reshape(1, -1)]
+    # scalar-prefetch operands (SMEM): per-(row tile, bank, input plane)
+    # liveness for the plane-skip gate, and the per-bank ADC full scale
+    live = jnp.any(
+        xs.reshape(bp // block_b, block_b, cfg.bx, n_banks, cfg.bank_n) != 0,
+        axis=(1, 4))                                  # [tiles, BX, banks]
+    live = jnp.transpose(live, (0, 2, 1)).reshape(-1).astype(jnp.int32)
+    scalars = [live, fs.astype(jnp.float32).reshape(-1)]
+    # Planes flatten into their trailing axis (free, row-major reshapes):
+    # input plane kx of bank k is the 2D tile (i, kx*banks + k) of
+    # [B, BX*N], weight plane ka of column tile j the tile
+    # (k, ka*col_tiles + j) of [N, BA*M].  One operand per plane keeps
+    # every block a dense 2D tile (no strided plane slicing in-kernel).
+    # Per-bank unmasked counts go bank-major, so each block is a legal
+    # (block_b, 1) tile of a [banks, B, 1] array.
+    col_tiles = mp // block_m
+    xs2 = xs.reshape(bp, cfg.bx * n_banks * cfg.bank_n)
+    ws2 = ws.reshape(n_banks * cfg.bank_n, cfg.ba * mp)
+    operands = [xs2] * cfg.bx + [ws2] * cfg.ba + [
+        jnp.transpose(nu)[:, :, None]]
     in_specs = [
-        pl.BlockSpec((block_b, cfg.bx, cfg.bank_n), lambda i, j, k: (i, 0, k)),
-        pl.BlockSpec((cfg.bank_n, cfg.ba, block_m), lambda i, j, k: (k, 0, j)),
-        pl.BlockSpec((block_b, 1), lambda i, j, k: (i, k)),
-        pl.BlockSpec((1, 1), lambda i, j, k: (0, k)),
+        pl.BlockSpec((block_b, cfg.bank_n),
+                     lambda i, j, k, *_, kx=kx: (i, kx * n_banks + k))
+        for kx in range(cfg.bx)
+    ] + [
+        pl.BlockSpec((cfg.bank_n, block_m),
+                     lambda i, j, k, *_, ka=ka: (k, ka * col_tiles + j))
+        for ka in range(cfg.ba)
+    ] + [
+        pl.BlockSpec((pl.Squeezed(), block_b, 1),
+                     lambda i, j, k, *_: (k, i, 0)),
     ]
     if fused:
         def col_vec(v, fill):
@@ -200,8 +237,8 @@ def cima_mvm_planes(
         def vec_spec(v):
             if v.shape[0] > 1:
                 return pl.BlockSpec((block_b, block_m),
-                                    lambda i, j, k: (i, j))
-            return pl.BlockSpec((1, block_m), lambda i, j, k: (0, j))
+                                    lambda i, j, k, *_: (i, j))
+            return pl.BlockSpec((1, block_m), lambda i, j, k, *_: (0, j))
 
         es, pb = col_vec(escale, 1.0), col_vec(pbias, 0.0)
         operands += [es, pb]
@@ -218,16 +255,20 @@ def cima_mvm_planes(
             act=(act or "") if fused else "",
             by_bits=(by_bits or 0) if fused else 0,
         ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_b, block_m), lambda i, j, k: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((block_b, block_m),
+                                   lambda i, j, k, *_: (i, j)),
+        ),
         out_shape=jax.ShapeDtypeStruct((bp, mp), jnp.float32),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="cima_bpbs_mvm",
-    )(*operands)
+    )(*scalars, *operands)
     return out[:b, :m]
 
 

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import _compat
 
 NEG_INF = -1e30
 
@@ -166,7 +165,7 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dp), jnp.float32),
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

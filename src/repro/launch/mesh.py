@@ -8,19 +8,29 @@ inter-pod links).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: shardings propagate
+    through GSPMD and ``with_sharding_constraint`` hints, which is what
+    the sharding rules and the ``shard_map`` dispatch are written for.
+    (JAX's own default is ``Explicit`` axes, under which reshapes of
+    sharded activations are refused at trace time.)"""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Degenerate mesh over whatever devices exist (tests / smoke runs)."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def make_serve_mesh(data: int = 1, model: int = 1):

@@ -29,6 +29,23 @@ from repro.models import decode_step, init_cache, prefill, splice_slot
 
 from .host import host_sync
 
+# Decode steps run at a whole number of DECODE_ROW_TILE rows (the TPU's
+# sublane tile); rows past the live requests are idle padding whose
+# samples are discarded.  XLA fuses and lowers a program according to
+# its shapes, so one request decoded at two row counts runs two
+# different programs: on a TPU, one-request Engine.generate and a
+# 4-slot scheduler computed different logits for the same request and
+# their greedy streams parted.  With Engine.generate, the slot batcher
+# and the paged scheduler all padded to the row tile, up to
+# DECODE_ROW_TILE requests decode with the same shapes on every path
+# (README "Serving semantics").
+DECODE_ROW_TILE = 8
+
+
+def decode_rows(batch: int) -> int:
+    """The row count a decode step over ``batch`` requests runs at."""
+    return -(-batch // DECODE_ROW_TILE) * DECODE_ROW_TILE
+
 
 @dataclasses.dataclass
 class ServeConfig:
@@ -211,6 +228,9 @@ class Engine:
         self._decode = jax.jit(self._meshed(
             lambda p, tok, cache: decode_step(p, tok, cache, cfg)),
             donate_argnums=2)
+        # writes a prefilled [B]-row cache into the first rows of a fresh
+        # decode_rows(B)-row cache (generate's decode width)
+        self._widen = jax.jit(self._meshed(splice_slot), donate_argnums=0)
         self._base_key = jax.random.PRNGKey(serve_cfg.seed)
         # decode steps actually issued by the last generate() call (the
         # all-rows-EOS early exit makes this < max_new_tokens - 1)
@@ -299,9 +319,12 @@ class Engine:
         """
         assert prompts.ndim == 2, "prompts must be a dense [B, S] batch"
         b = prompts.shape[0]
+        w = decode_rows(b)
         eos = self.scfg.eos_id
         rids = np.arange(b) if request_ids is None else np.asarray(request_ids)
         logits, cache = self._prefill(self.params, prompts, frontend_embeds)
+        if w != b:
+            cache = self._widen(self.init_cache(w), cache, np.int32(0))
         tok = self.sample(logits, rids, np.zeros(b, np.int64))
         out = [tok]
         done = jnp.zeros_like(tok, dtype=bool)
@@ -321,7 +344,9 @@ class Engine:
                         done, reason="eos early-exit poll, amortized over "
                         "eos_check_every decode steps").all()):
                     break
-            logits, cache = self._decode(self.params, tok, cache)
+            logits, cache = self._decode(self.params,
+                                         jnp.pad(tok, (0, w - b)), cache)
+            logits = logits[:b]
             self.last_decode_steps += 1
             nxt = self.sample(logits, rids, np.full(b, t))
             if eos >= 0:
@@ -430,9 +455,10 @@ class ContinuousBatcher:
         arrivals via ``submit``; while it returns True the loop keeps
         polling instead of exiting when both queue and slots drain."""
         b = self.n_slots
+        w = decode_rows(b)
         eos = self.scfg.eos_id
-        cache = self.engine.init_cache(b)
-        cur = np.zeros(b, np.int32)
+        cache = self.engine.init_cache(w)
+        cur = np.zeros(w, np.int32)
         slots: list[Optional[_Slot]] = [None] * b
         emitted: dict[int, list[int]] = {}
         feeding = feed is not None
@@ -483,8 +509,9 @@ class ContinuousBatcher:
                                                 jnp.asarray(cur), cache)
             self.stats["decode_steps"] += 1
             self.stats["slot_steps"] += len(active)
-            rids = np.asarray([s.rid if s else 0 for s in slots])
-            steps = np.asarray([s.n_gen if s else 0 for s in slots])
+            idle = [0] * (w - b)
+            rids = np.asarray([s.rid if s else 0 for s in slots] + idle)
+            steps = np.asarray([s.n_gen if s else 0 for s in slots] + idle)
             toks = host_sync(self.engine.sample(logits, rids, steps),
                              reason="slot-batcher reference loop: one "
                              "token sync per decode step by design")

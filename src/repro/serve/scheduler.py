@@ -63,7 +63,7 @@ from repro.models import decode_step, prefill_resume
 from repro.analysis.sanitize import active as _san_active
 
 from . import kv
-from .engine import Engine, ServeConfig
+from .engine import Engine, ServeConfig, decode_rows
 from .host import host_sync
 
 
@@ -108,26 +108,33 @@ class PagedScheduler:
         self.engine = Engine(params, cfg, serve_cfg)
         self.params, self.cfg, self.scfg = self.engine.params, cfg, serve_cfg
         self.n_slots = n_slots
-        self.layout = kv.build_layout(cfg, n_slots, serve_cfg.max_seq,
+        # the device state holds decode_rows(n_slots) rows; the rows past
+        # n_slots never admit a request (their tables stay sentinel), and
+        # the default pool still covers n_slots rows
+        rows = decode_rows(n_slots)
+        self.layout = kv.build_layout(cfg, rows, serve_cfg.max_seq,
                                       serve_cfg.kv_block_size, num_blocks)
+        if num_blocks is None:
+            self.layout = dataclasses.replace(
+                self.layout,
+                num_blocks=max(1, n_slots * self.layout.table_width))
         self.alloc = kv.BlockAllocator(self.layout.num_blocks)
         self.paged = kv.init_paged_cache(self.layout)
         if self.engine.mesh is not None:
             dsize = int(dict(self.engine.mesh.shape).get("data", 1))
-            if dsize > 1 and n_slots % dsize:
+            if dsize > 1 and rows % dsize:
                 import warnings
                 warnings.warn(
-                    f"n_slots={n_slots} is not divisible by the mesh "
-                    f"'data' axis ({dsize}): slot state and positions "
-                    f"replicate instead of sharding — size the slot pool "
-                    f"as a multiple of data for the intended capacity",
+                    f"{rows} decode rows (n_slots={n_slots}) are not "
+                    f"divisible by the mesh 'data' axis ({dsize}): slot "
+                    f"state and positions replicate instead of sharding",
                     stacklevel=2)
             specs = kv.paged_cache_specs(
                 jax.eval_shape(lambda: self.paged), self.layout,
                 self.engine.mesh, serve_cfg.shard_policy)
             self.paged = jax.device_put(self.paged, specs)
         # host-side mirrors: the scheduler owns block placement
-        self.tables = np.full((n_slots, self.layout.table_width),
+        self.tables = np.full((rows, self.layout.table_width),
                               self.layout.sentinel, np.int32)
         self._row_blocks: list[list[int]] = [[] for _ in range(n_slots)]
         self._pos_host = [0] * n_slots
@@ -353,9 +360,10 @@ class PagedScheduler:
             return
         K = self.scfg.decode_block
         eos = self.scfg.eos_id
-        cur = np.zeros(self.n_slots, np.int32)
-        rids = np.zeros(self.n_slots, np.int32)
-        steps = np.zeros(self.n_slots, np.int32)
+        rows = len(self.tables)
+        cur = np.zeros(rows, np.int32)
+        rids = np.zeros(rows, np.int32)
+        steps = np.zeros(rows, np.int32)
         for i in active:
             s = self.slots[i]
             cur[i], rids[i], steps[i] = s.cur, s.req.rid, s.n_gen

@@ -240,6 +240,21 @@ def test_acc01_trace_record_inside_shard_map():
         """) == ["ACC01"]
 
 
+def test_acc01_trace_record_inside_jax_shard_map():
+    """The top-level ``jax.shard_map`` spelling is recognised too."""
+    assert codes("""
+        import jax
+        from repro.accel.context import trace
+
+        def launch(mesh, x):
+            def body(x):
+                trace(x)
+                return x
+            return jax.shard_map(body, mesh=mesh, in_specs=None,
+                                 out_specs=None, check_vma=False)(x)
+        """) == ["ACC01"]
+
+
 def test_acc01_record_outside_shard_map_is_clean():
     assert codes("""
         from jax.experimental.shard_map import shard_map

@@ -1,0 +1,89 @@
+"""The main-path CIMA kernel compiled for a described TPU v5e.
+
+Nothing runs: each case lowers ``cima_bpbs_mvm`` with ``interpret=False``
+and compiles it with the TPU compiler for one chip of a described
+``v5e:2x2`` topology, at olmo-1b's published widths and B_A = B_X = 4.
+That is what refuses a kernel that interpret mode accepts (illegal block
+tiling, layouts Mosaic cannot relayout, VMEM overuse).  The topology is
+described inside a fixture, so where no TPU compiler is installed these
+tests skip, and importing this file touches no device state.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.bpbs import BpbsConfig
+from repro.kernels.cima_mvm import cima_mvm_from_planes
+
+CFG = BpbsConfig(ba=4, bx=4)          # skip_zero_planes on (the default)
+DECODE_ROWS = 16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "no compiler"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A described-device compile cannot be read back without the chip:
+    keep it out of the persistent cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(one_chip, n, m, fused):
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [arg((DECODE_ROWS, n), jnp.int8),          # quantized inputs
+            arg((n, CFG.ba, m), jnp.int8)]            # CimaImage.ws planes
+    if fused:
+        # per-row escale: batch-decoupled input scales folded into the
+        # datapath scale registers, then silu and B_y = 32 saturation
+        args.append(arg((DECODE_ROWS, m), jnp.float32))
+
+        def fn(x, ws, es):
+            return cima_mvm_from_planes(x, ws, CFG, interpret=False,
+                                        escale=es, act="silu", by_bits=32)
+    else:
+        def fn(x, ws):
+            return cima_mvm_from_planes(x, ws, CFG, interpret=False)
+
+    compiled = jax.jit(fn).lower(*args).compile()
+    want = sum(a.size * a.dtype.itemsize for a in args)
+    return compiled, want
+
+
+@pytest.mark.parametrize("n,m,fused", [
+    (2048, 8192, False),     # one bank: attention / mlp.gate, mlp.up
+    (8192, 2048, False),     # four banks: mlp.down (per-bank nu, fs)
+    (2048, 8192, True),      # fused epilogue with per-row escale + silu
+], ids=["single_bank", "multi_bank", "fused_silu_row_escale"])
+def test_cima_mvm_compiles_for_v5e(one_chip, no_compile_cache, n, m, fused):
+    assert CFG.skip_zero_planes
+    compiled, want = _compile(one_chip, n, m, fused)
+    assert compiled.memory_analysis().argument_size_in_bytes == want
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "cima_bpbs_mvm" in text

@@ -29,9 +29,10 @@ def run_py(code: str, devices: int = 8, timeout: int = 560) -> str:
 def test_pick_spec_divisibility():
     out = run_py("""
         import jax
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.distributed.sharding import pick_spec
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         # divisible on both dims
         assert pick_spec((8, 16), mesh, [["data"], ["model"]]) == P("data", "model")
         # 6 not divisible by 4 -> replicate that dim
@@ -49,11 +50,12 @@ def test_pick_spec_divisibility():
 def test_param_rules():
     out = run_py("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.configs import get_config
         from repro.distributed.sharding import param_specs
         from repro.models import init_params
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_config("deepseek-v2-lite-16b").reduced()
         shapes = jax.eval_shape(lambda k: init_params(cfg, k, 32), jax.random.PRNGKey(0))
         specs = param_specs(shapes, mesh)
@@ -75,6 +77,7 @@ def test_sharded_train_step_matches_single_device():
     """The distributed invariant: identical loss on 1 vs 8 devices."""
     code = """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.distributed import sharding as shd, autoshard
         from repro.models import init_params
@@ -95,7 +98,7 @@ def test_sharded_train_step_matches_single_device():
 
         losses = []
         if len(jax.devices()) == 8:
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             autoshard.set_mesh(mesh)
             state_shapes = jax.eval_shape(lambda: state)
             state_sh = shd.state_specs(state_shapes, mesh)

@@ -401,11 +401,12 @@ def test_paged_parity_2dev_mesh():
 
     out = run_py("""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.models import init_params
         from repro.serve import ContinuousBatcher, PagedScheduler, ServeConfig
 
-        mesh = jax.make_mesh((2,), ("model",))
+        mesh = make_mesh((2,), ("model",))
         rng = np.random.default_rng(0)
 
         def run(server, prompts):

@@ -47,6 +47,41 @@ def test_slot_splice_parity_greedy(name):
         assert results[rid] == solo, (rid, results[rid], solo)
 
 
+def test_every_serving_path_decodes_at_the_row_tile():
+    """Engine.generate, the slot batcher and the paged scheduler all run
+    their decode steps at ``decode_rows(batch)`` rows, so up to
+    DECODE_ROW_TILE requests decode with the same shapes on every path —
+    XLA compiles a different program for each row count, which on a TPU
+    let a request's stream depend on how many rows shared its step."""
+    from repro.serve import PagedScheduler
+    from repro.serve.engine import DECODE_ROW_TILE, decode_rows
+
+    assert [decode_rows(b) for b in (1, 3, 8, 9)] == [8, 8, 8, 16]
+    cfg, params, scfg = _setup(max_new_tokens=4, decode_block=2)
+    prompts = _ragged_prompts(3, cfg.vocab)
+    widths = []
+
+    def spy(fn, row_arg):
+        def wrapped(*args):
+            widths.append(args[row_arg].shape[0])
+            return fn(*args)
+        return wrapped
+
+    eng = Engine(params, cfg, scfg)
+    eng._decode = spy(eng._decode, 1)
+    solo = [eng.generate(jnp.asarray(p[None]))[0].tolist() for p in prompts]
+    cb = ContinuousBatcher(params, cfg, scfg, n_slots=3)
+    cb.engine._decode = spy(cb.engine._decode, 1)
+    ps = PagedScheduler(params, cfg, scfg, n_slots=3)
+    ps._block = spy(ps._block, 3)
+    for server in (cb, ps):
+        rids = [server.submit(p) for p in prompts]
+        streams = server.run()
+        assert [streams[r] for r in rids] == solo
+    assert widths and set(widths) == {DECODE_ROW_TILE}
+    assert ps.layout.num_blocks == 3 * ps.layout.table_width
+
+
 def test_slot_splice_parity_with_eos_truncation():
     """Parity must hold through EOS retirement: pick a token the greedy
     run actually emits, declare it EOS, and check the batcher truncates

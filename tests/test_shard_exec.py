@@ -53,15 +53,16 @@ def test_shard_policy_object_and_no_global_shims():
     module default is an immutable constant."""
     out = run_py("""
         import jax
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.distributed import ShardPolicy
         from repro.distributed import sharding
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         p2d, pf = ShardPolicy("2d"), ShardPolicy("fsdp")
         assert p2d.dp_axes(mesh) == ("data",)
         assert pf.dp_axes(mesh) == ("data", "model")
         # a model-only serving mesh has no dp axes at all under 2d
-        m1 = jax.make_mesh((8,), ("model",))
+        m1 = make_mesh((8,), ("model",))
         assert p2d.dp_axes(m1) == ()
         # the same shapes under the two policies disagree — explicitly,
         # per call, with no global mutated in between
@@ -95,9 +96,10 @@ def test_cache_specs_batch1_deterministic():
     the splice-compatibility contract."""
     out = run_py("""
         import jax
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.distributed import cache_specs
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
         # scanned-layer KV leaf [L, B, S, H, D] at B=1: dim 0 (L=8, which
         # IS divisible by the model axis) must NOT be claimed — dim 1 is
         # the batch, and "model" goes to the largest divisible non-batch
@@ -168,6 +170,7 @@ def test_partition_and_per_device_capacity():
 
 _PARITY = """
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_config
     from repro.models import init_params, prefill, decode_step
     from repro.accel import build_program, install_program
@@ -175,7 +178,7 @@ _PARITY = """
 
     DEVICES = {devices}
     BACKEND = "{backend}"
-    mesh = jax.make_mesh((DEVICES,), ("model",))
+    mesh = make_mesh((DEVICES,), ("model",))
     for arch in ("olmo-1b", "mamba2-130m"):
         # bank_n=16 aligns per-bank ADC boundaries with device boundaries
         # for every managed N at 2/4/8 shards -> row-parallel bpbs is
@@ -256,6 +259,7 @@ def test_sharded_trace_counts_match_unsharded():
     count/loads as the unsharded trace for the same workload."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.models import init_params, prefill
         from repro import accel
@@ -267,7 +271,7 @@ def test_sharded_trace_counts_match_unsharded():
         params = init_params(cfg, jax.random.PRNGKey(0), max_seq=64)
         toks = jnp.asarray(np.random.default_rng(0).integers(
             1, cfg.vocab, (2, 8)), jnp.int32)
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
 
         def traced(prog, mesh_):
             p = install_program(params, prog, cfg)
@@ -314,6 +318,7 @@ def test_sharded_batcher_matches_unsharded_batcher():
     digital_int)."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.models import init_params
         from repro.serve.engine import ContinuousBatcher, ServeConfig
@@ -321,7 +326,7 @@ def test_sharded_batcher_matches_unsharded_batcher():
         cfg = get_config("olmo-1b").reduced().with_accel(
             "digital_int", ba=4, bx=4, bank_n=16)
         params = init_params(cfg, jax.random.PRNGKey(0), max_seq=128)
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
         rng = np.random.default_rng(0)
         prompts = [rng.integers(1, cfg.vocab, (int(l),)).astype(np.int32)
                    for l in (5, 9, 17, 4)]
@@ -349,13 +354,14 @@ def test_sharded_slot_splice_parity_vs_solo():
     batch-width independent there, so any mismatch is a splice bug)."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.models import init_params
         from repro.serve.engine import ContinuousBatcher, Engine, ServeConfig
 
         cfg = get_config("olmo-1b").reduced()        # all-digital policy
         params = init_params(cfg, jax.random.PRNGKey(0), max_seq=128)
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
         rng = np.random.default_rng(0)
         prompts = [rng.integers(1, cfg.vocab, (int(l),)).astype(np.int32)
                    for l in (5, 9, 17, 4, 11)]
@@ -381,6 +387,7 @@ def test_sharded_slot_splice_parity_vs_solo():
 
 _FUSED_PARITY = """
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro import accel
     from repro.core.datapath import Postreduce
     from repro.accel.program import _compile_image, partition_for
@@ -394,7 +401,7 @@ _FUSED_PARITY = """
         scale=jnp.asarray(rng.normal(size=(64,)), jnp.float32),
         bias=jnp.asarray(rng.normal(size=(64,)), jnp.float32),
         act="relu", saturate=True)
-    mesh = jax.make_mesh((devices,), ("model",))
+    mesh = make_mesh((devices,), ("model",))
     # bank_n = per-device rows so row-parallel bpbs stays bit-exact
     for tag in ("mlp.gate", "mlp.down"):        # -> col, row partitions
         for backend in ("digital_int", "bpbs", "pallas"):

@@ -38,7 +38,7 @@ def _block_sparse(rng, batch, n, sparsity):
 
 # ------------------------------------------------------- plane-skip parity
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(coding=st.sampled_from([Coding.XNOR, Coding.AND]),
        bits=st.sampled_from([(1, 1), (2, 3), (4, 4)]),
        sparsity=st.floats(0.0, 0.95),
@@ -120,6 +120,7 @@ def test_plane_skip_parity_2dev_shard():
     out = run_py("""
         import dataclasses
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro import accel
         from repro.accel.program import _compile_image
         from repro.distributed.autoshard import use_mesh
@@ -129,7 +130,7 @@ def test_plane_skip_parity_2dev_shard():
         x[:, :128] = 0.0                        # block-feature sparsity
         x = jnp.asarray(x)
         w = jnp.asarray(rng.normal(size=(256, 64)), jnp.float32)
-        mesh = jax.make_mesh((2,), ("model",))
+        mesh = make_mesh((2,), ("model",))
         for part in ("col", "row"):
             # bank_n = per-device rows so row-parallel bpbs is bit-exact
             spec = accel.ExecSpec(backend="bpbs", ba=4, bx=4, bank_n=128)

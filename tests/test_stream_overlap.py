@@ -221,6 +221,7 @@ def test_program_manager_threads_stream_knobs():
 
 _MESH2D = """
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_config
     from repro.models import init_params, prefill, decode_step
     from repro import accel
@@ -229,7 +230,7 @@ _MESH2D = """
     from repro.launch.mesh import make_serve_mesh
 
     DEVICES = {devices}
-    mesh1 = jax.make_mesh((DEVICES,), ("model",))
+    mesh1 = make_mesh((DEVICES,), ("model",))
     mesh2 = make_serve_mesh(data=2, model=DEVICES // 2)
     cfg = get_config("olmo-1b").reduced().with_accel("digital_int",
                                                      ba=4, bx=4, bank_n=16)
