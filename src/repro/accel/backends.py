@@ -43,9 +43,10 @@ def quantize_input(x: jax.Array, spec: ExecSpec) -> QTensor:
     """
     from repro.distributed.autoshard import cs
 
-    qx = quantize(x, spec.bx, spec.coding, per_row=spec.x_per_row)
-    q_int = cs(qx.q.astype(jnp.int8), ("dp",))
-    return dataclasses.replace(qx, q=q_int)
+    with jax.named_scope("cima.quantize_x"):
+        qx = quantize(x, spec.bx, spec.coding, per_row=spec.x_per_row)
+        q_int = cs(qx.q.astype(jnp.int8), ("dp",))
+        return dataclasses.replace(qx, q=q_int)
 
 
 def weight_grid(w: jax.Array, spec: ExecSpec,
@@ -57,11 +58,12 @@ def weight_grid(w: jax.Array, spec: ExecSpec,
     call.
     """
     img = ctx.image
-    if img is not None:
-        return QTensor(img.wq.astype(jnp.float32), img.scale,
-                       spec.ba, spec.coding)
-    return quantize(w, spec.ba, spec.coding,
-                    axis=1 if spec.per_channel else None)
+    with jax.named_scope("cima.quantize_w"):
+        if img is not None:
+            return QTensor(img.wq.astype(jnp.float32), img.scale,
+                           spec.ba, spec.coding)
+        return quantize(w, spec.ba, spec.coding,
+                        axis=1 if spec.per_channel else None)
 
 
 def weight_planes_for(w: jax.Array, spec: ExecSpec,
@@ -77,12 +79,13 @@ def weight_planes_for(w: jax.Array, spec: ExecSpec,
     Fallback: quantize + decompose + transpose per call.
     """
     img = ctx.image
-    if img is not None:
-        return img.ws.astype(jnp.float32), img.scale
-    qw = quantize(w, spec.ba, spec.coding,
-                  axis=1 if spec.per_channel else None)
-    return jnp.transpose(weight_planes(qw.q, spec.bpbs()), (0, 2, 1)), \
-        qw.scale
+    with jax.named_scope("cima.quantize_w"):
+        if img is not None:
+            return img.ws.astype(jnp.float32), img.scale
+        qw = quantize(w, spec.ba, spec.coding,
+                      axis=1 if spec.per_channel else None)
+        return jnp.transpose(weight_planes(qw.q, spec.bpbs()),
+                             (0, 2, 1)), qw.scale
 
 
 def quantize_operands(x: jax.Array, w: jax.Array,
@@ -97,8 +100,9 @@ def quantize_operands(x: jax.Array, w: jax.Array,
 
 def rescale(y_int: jax.Array, x_scale: jax.Array, w_scale: jax.Array,
             spec: ExecSpec) -> jax.Array:
-    sw = w_scale if not spec.per_channel else w_scale.reshape(1, -1)
-    return y_int * x_scale * sw
+    with jax.named_scope("cima.post"):
+        sw = w_scale if not spec.per_channel else w_scale.reshape(1, -1)
+        return y_int * x_scale * sw
 
 
 def apply_post(y: jax.Array, post, spec: ExecSpec) -> jax.Array:
@@ -110,7 +114,8 @@ def apply_post(y: jax.Array, post, spec: ExecSpec) -> jax.Array:
     by construction)."""
     if post is None:
         return y
-    return post.apply(y, spec.bx, spec.ba)
+    with jax.named_scope("cima.post"):
+        return post.apply(y, spec.bx, spec.ba)
 
 
 @register_backend("digital")
@@ -183,8 +188,9 @@ def pallas(x: jax.Array, w: jax.Array, spec: ExecSpec,
     if img is not None:
         ws_planes, w_scale = img.ws, img.scale
     else:
-        qw = quantize(w, spec.ba, spec.coding,
-                      axis=1 if spec.per_channel else None)
+        with jax.named_scope("cima.quantize_w"):
+            qw = quantize(w, spec.ba, spec.coding,
+                          axis=1 if spec.per_channel else None)
         ws_planes, w_scale = None, qw.scale
 
     post = ctx.post

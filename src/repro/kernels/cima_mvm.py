@@ -179,20 +179,22 @@ def cima_mvm_planes(
     assert n_w == n and bx == cfg.bx and ba == cfg.ba
     n_banks = -(-n // cfg.bank_n)
 
-    xs = _pad_to(_pad_to(xs, 0, block_b), 2, cfg.bank_n)
-    ws = _pad_to(_pad_to(ws, 0, cfg.bank_n), 2, block_m)
-    nu = _pad_to(nu, 0, block_b)
+    with jax.named_scope("cima.pad"):
+        xs = _pad_to(_pad_to(xs, 0, block_b), 2, cfg.bank_n)
+        ws = _pad_to(_pad_to(ws, 0, cfg.bank_n), 2, block_m)
+        nu = _pad_to(nu, 0, block_b)
     bp, mp = xs.shape[0], ws.shape[2]
 
     fused = (escale is not None or pbias is not None
              or bool(act) or bool(by_bits))
     # scalar-prefetch operands (SMEM): per-(row tile, bank, input plane)
     # liveness for the plane-skip gate, and the per-bank ADC full scale
-    live = jnp.any(
-        xs.reshape(bp // block_b, block_b, cfg.bx, n_banks, cfg.bank_n) != 0,
-        axis=(1, 4))                                  # [tiles, BX, banks]
-    live = jnp.transpose(live, (0, 2, 1)).reshape(-1).astype(jnp.int32)
-    scalars = [live, fs.astype(jnp.float32).reshape(-1)]
+    with jax.named_scope("cima.liveness"):
+        live = jnp.any(
+            xs.reshape(bp // block_b, block_b, cfg.bx, n_banks,
+                       cfg.bank_n) != 0,
+            axis=(1, 4))                              # [tiles, BX, banks]
+        live = jnp.transpose(live, (0, 2, 1)).reshape(-1).astype(jnp.int32)
     # Planes flatten into their trailing axis (free, row-major reshapes):
     # input plane kx of bank k is the 2D tile (i, kx*banks + k) of
     # [B, BX*N], weight plane ka of column tile j the tile
@@ -201,10 +203,12 @@ def cima_mvm_planes(
     # Per-bank unmasked counts go bank-major, so each block is a legal
     # (block_b, 1) tile of a [banks, B, 1] array.
     col_tiles = mp // block_m
-    xs2 = xs.reshape(bp, cfg.bx * n_banks * cfg.bank_n)
-    ws2 = ws.reshape(n_banks * cfg.bank_n, cfg.ba * mp)
-    operands = [xs2] * cfg.bx + [ws2] * cfg.ba + [
-        jnp.transpose(nu)[:, :, None]]
+    with jax.named_scope("cima.planes"):
+        scalars = [live, fs.astype(jnp.float32).reshape(-1)]
+        xs2 = xs.reshape(bp, cfg.bx * n_banks * cfg.bank_n)
+        ws2 = ws.reshape(n_banks * cfg.bank_n, cfg.ba * mp)
+        operands = [xs2] * cfg.bx + [ws2] * cfg.ba + [
+            jnp.transpose(nu)[:, :, None]]
     in_specs = [
         pl.BlockSpec((block_b, cfg.bank_n),
                      lambda i, j, k, *_, kx=kx: (i, kx * n_banks + k))
@@ -218,6 +222,7 @@ def cima_mvm_planes(
                      lambda i, j, k, *_: (k, i, 0)),
     ]
     if fused:
+        @jax.named_scope("cima.pad")
         def col_vec(v, fill):
             if v is None:
                 v = jnp.full((1, m), fill, jnp.float32)
@@ -245,7 +250,7 @@ def cima_mvm_planes(
         in_specs += [vec_spec(es), vec_spec(pb)]
 
     grid = (bp // block_b, mp // block_m, n_banks)
-    out = pl.pallas_call(
+    kernel = pl.pallas_call(
         functools.partial(
             _kernel,
             cfg=cfg,
@@ -268,8 +273,11 @@ def cima_mvm_planes(
         ),
         interpret=interpret,
         name="cima_bpbs_mvm",
-    )(*scalars, *operands)
-    return out[:b, :m]
+    )
+    with jax.named_scope("cima.kernel"):
+        out = kernel(*scalars, *operands)
+    with jax.named_scope("cima.post"):
+        return out[:b, :m]
 
 
 def prepare_inputs(x_q: jax.Array, cfg: BpbsConfig):
@@ -279,13 +287,15 @@ def prepare_inputs(x_q: jax.Array, cfg: BpbsConfig):
 
     lead = x_q.shape[:-1]
     n = x_q.shape[-1]
-    x2 = x_q.reshape(-1, n)
-    planes, mask = input_planes(x2, cfg)           # [B, N, BX], [B, N]
-    xs = jnp.transpose(planes, (0, 2, 1)).astype(jnp.int8)
     n_banks = -(-n // cfg.bank_n)
     pad = n_banks * cfg.bank_n - n
-    mask_p = jnp.pad(mask, ((0, 0), (0, pad)))
-    nu = mask_p.reshape(-1, n_banks, cfg.bank_n).sum(-1).astype(jnp.float32)
+    with jax.named_scope("cima.planes"):
+        x2 = x_q.reshape(-1, n)
+        planes, mask = input_planes(x2, cfg)       # [B, N, BX], [B, N]
+        xs = jnp.transpose(planes, (0, 2, 1)).astype(jnp.int8)
+        mask_p = jnp.pad(mask, ((0, 0), (0, pad)))
+        nu = mask_p.reshape(-1, n_banks, cfg.bank_n).sum(-1).astype(
+            jnp.float32)
     return xs, nu, lead
 
 
@@ -297,7 +307,8 @@ def bank_full_scales(n: int, cfg: BpbsConfig) -> jax.Array:
     sizes = np.minimum(
         np.full(n_banks, cfg.bank_n), n - np.arange(n_banks) * cfg.bank_n
     )
-    return jnp.asarray(sizes, dtype=jnp.float32)
+    with jax.named_scope("cima.planes"):
+        return jnp.asarray(sizes, dtype=jnp.float32)
 
 
 def prepare_weights(w_q: jax.Array, cfg: BpbsConfig):
@@ -307,8 +318,9 @@ def prepare_weights(w_q: jax.Array, cfg: BpbsConfig):
     stores once at program-load time."""
     from repro.core.bpbs import weight_planes
 
-    wp = weight_planes(w_q, cfg)                   # [N, M, BA]
-    ws = jnp.transpose(wp, (0, 2, 1)).astype(jnp.int8)
+    with jax.named_scope("cima.planes"):
+        wp = weight_planes(w_q, cfg)               # [N, M, BA]
+        ws = jnp.transpose(wp, (0, 2, 1)).astype(jnp.int8)
     return ws, bank_full_scales(w_q.shape[0], cfg)
 
 
@@ -331,7 +343,8 @@ def cima_mvm(
     ws, fs = prepare_weights(w_q, cfg)
     y = cima_mvm_planes(xs, ws, nu, fs, cfg, block_b, block_m, interpret,
                         escale, pbias, act, by_bits)
-    return y.reshape(*lead, w_q.shape[1])
+    with jax.named_scope("cima.post"):
+        return y.reshape(*lead, w_q.shape[1])
 
 
 def cima_mvm_from_planes(
@@ -353,4 +366,5 @@ def cima_mvm_from_planes(
     fs = bank_full_scales(ws.shape[0], cfg)
     y = cima_mvm_planes(xs, ws, nu, fs, cfg, block_b, block_m, interpret,
                         escale, pbias, act, by_bits)
-    return y.reshape(*lead, ws.shape[2])
+    with jax.named_scope("cima.post"):
+        return y.reshape(*lead, ws.shape[2])

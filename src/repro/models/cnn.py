@@ -93,6 +93,39 @@ def update_bn_stats(params: dict, stats, momentum: float = 0.9) -> dict:
     return new
 
 
+def _layer(x, i: int, layer, p: dict, net: CnnConfig, train: bool,
+           bn_stats: list):
+    """One layer of :func:`cnn_forward`: im2col (or flatten), the CIMA
+    matmul with its datapath epilogue, and the optional pool."""
+    with jax.named_scope("cnn.im2col"):
+        if layer.kind == "conv":
+            h = _im2col(x)                               # [B,H,W,9*Cin]
+        else:
+            h = x.reshape(x.shape[0], -1)                # flatten
+    spec = net.policy.resolve(f"layer{i}", kind=layer.kind, layer=i)
+    last = i == len(net.layers) - 1
+    if train:
+        y = accel.matmul(h, p["w"], spec, dtype=jnp.float32)
+        y, st = _batchnorm(y, p["bn_scale"], p["bn_bias"])
+        bn_stats.append(jax.tree_util.tree_map(jax.lax.stop_gradient, st))
+        if not last:
+            y = ste_sign(y) if net.readout == "abn" else jax.nn.relu(y)
+    else:
+        s, b = fold_batchnorm(p["bn_scale"], p["bn_bias"],
+                              p["bn_mean"], p["bn_var"])
+        post = Postreduce(
+            scale=s, bias=b,
+            act=None if last else
+            ("sign" if net.readout == "abn" else "relu"),
+            saturate=True)
+        y = accel.matmul(h, p["w"], spec, dtype=jnp.float32, post=post)
+    if layer.kind == "conv" and layer.pool:
+        with jax.named_scope("cnn.pool"):
+            b_, hh, ww, c = y.shape
+            y = y.reshape(b_, hh // 2, 2, ww // 2, 2, c).max(axis=(2, 4))
+    return y
+
+
 def cnn_forward(params, images, net: CnnConfig,
                 backend: Optional[str] = None, train: bool = False):
     """images: [B, 32, 32, 3] -> logits [B, 10]  (plus the per-layer BN
@@ -114,38 +147,11 @@ def cnn_forward(params, images, net: CnnConfig,
     ov = (accel.override(backend=backend) if backend is not None
           else contextlib.nullcontext())
     x = images
-    n_layers = len(net.layers)
     bn_stats = []
     with ov:
         for i, (layer, p) in enumerate(zip(net.layers, params["layers"])):
-            if layer.kind == "conv":
-                h = _im2col(x)                           # [B,H,W,9*Cin]
-            else:
-                h = x.reshape(x.shape[0], -1)            # flatten
-            spec = net.policy.resolve(f"layer{i}", kind=layer.kind, layer=i)
-            last = i == n_layers - 1
-            if train:
-                y = accel.matmul(h, p["w"], spec, dtype=jnp.float32)
-                y, st = _batchnorm(y, p["bn_scale"], p["bn_bias"])
-                bn_stats.append(jax.tree_util.tree_map(
-                    jax.lax.stop_gradient, st))
-                if not last:
-                    y = ste_sign(y) if net.readout == "abn" \
-                        else jax.nn.relu(y)
-            else:
-                s, b = fold_batchnorm(p["bn_scale"], p["bn_bias"],
-                                      p["bn_mean"], p["bn_var"])
-                post = Postreduce(
-                    scale=s, bias=b,
-                    act=None if last else
-                    ("sign" if net.readout == "abn" else "relu"),
-                    saturate=True)
-                y = accel.matmul(h, p["w"], spec, dtype=jnp.float32,
-                                 post=post)
-            if layer.kind == "conv" and layer.pool:
-                b_, hh, ww, c = y.shape
-                y = y.reshape(b_, hh // 2, 2, ww // 2, 2, c).max(axis=(2, 4))
-            x = y
+            with jax.named_scope(f"cnn.layer{i}"):
+                x = _layer(x, i, layer, p, net, train, bn_stats)
     return (x, bn_stats) if train else x
 
 
