@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from .adc import adc_quantize_sum
-from .quant import Coding, int_to_planes, plane_weights
+from .quant import Coding, int_plane_list, int_to_planes, plane_weights
 from .sparsity import element_mask
 
 
@@ -70,18 +70,33 @@ def weight_planes(w_q: jax.Array, cfg: BpbsConfig) -> jax.Array:
     return int_to_planes(w_q, cfg.ba, cfg.coding)
 
 
+def input_plane_list(x_q: jax.Array, cfg: BpbsConfig) -> tuple[list, jax.Array]:
+    """Input bit planes as a list of ``x_q``-shaped arrays (plane order),
+    with the controller mask folded in, and the mask.
+
+    XNOR planes of masked (zero-valued) elements are zeroed — the
+    capacitor-reset behaviour; AND planes of zero elements are all-zero
+    by construction.  The Pallas kernel evaluates this on each VMEM tile
+    of the input grid; :func:`input_planes` stacks it for the fast path.
+    """
+    mask = element_mask(x_q)
+    if cfg.coding == Coding.XNOR and cfg.bx == 1:
+        # one +-1 plane with the masked (zero) elements reset: the grid
+        # {-1, 0, +1} itself
+        return [x_q.astype(jnp.float32)], mask
+    planes = int_plane_list(x_q, cfg.bx, cfg.coding)
+    if cfg.coding == Coding.XNOR:
+        planes = [p * mask for p in planes]
+    return planes, mask
+
+
 def input_planes(x_q: jax.Array, cfg: BpbsConfig) -> tuple[jax.Array, jax.Array]:
     """Input bit planes [..., N, B_X] with the controller mask folded in.
 
-    Returns ``(planes, mask)``.  XNOR planes of masked (zero-valued)
-    elements are zeroed — the capacitor-reset behaviour; AND planes of
-    zero elements are all-zero by construction.
+    Returns ``(planes, mask)``; see :func:`input_plane_list`.
     """
-    planes = int_to_planes(x_q, cfg.bx, cfg.coding)
-    mask = element_mask(x_q)
-    if cfg.coding == Coding.XNOR:
-        planes = planes * mask[..., None]
-    return planes, mask
+    planes, mask = input_plane_list(x_q, cfg)
+    return jnp.stack(planes, axis=-1), mask
 
 
 def adc_full_scale(nu: jax.Array, bank_rows, cfg: BpbsConfig):

@@ -141,57 +141,62 @@ def quantize(
         return QTensor(q, scale, bits, coding)
 
 
+def int_plane_list(q: jax.Array, bits: int, coding: Coding) -> list:
+    """The bit planes of :func:`int_to_planes` as a list in plane order,
+    each shaped like ``q`` (float32, exact small integers).
+
+    The form a kernel evaluates: every step is elementwise on ``q``, so
+    the planes of one VMEM tile come out as dense tiles of that shape.
+    Divisions by powers of two are multiplications by their exact
+    reciprocals.
+    """
+    coding = Coding(coding)
+    q = q.astype(jnp.float32)
+    if coding == Coding.XNOR:
+        if bits == 1:
+            return [jnp.where(q >= 0, 1.0, -1.0)]
+        big = 2.0 ** (bits - 1)
+        u = (q + big) * 0.5                       # in [0, 2^(B-1)], integer
+        top = u >= big
+        e = jnp.where(top, 1.0, 0.0)              # second LSB-weight plane
+        v = jnp.where(top, big - 1.0, u)          # u == big -> v = all-ones
+        # v in [0, 2^(B-1)-1]: standard binary over weights [2^(B-2) .. 1]
+        planes = []
+        rem = v
+        for k in range(bits - 2, -1, -1):
+            w = 2.0 ** k
+            b = jnp.floor(rem * (1.0 / w))
+            rem = rem - b * w
+            planes.append(b)
+        planes.append(e)
+        return [2.0 * b - 1.0 for b in planes]    # {0,1} -> {-1,+1}
+    if bits == 1:
+        return [jnp.clip(q, 0, 1)]
+    # two's complement: q + 2^(B-1) = unsigned B-bit value
+    u = q + 2.0 ** (bits - 1)
+    # weights [1, 2, ..., 2^(B-2), -2^(B-1)]; extract MSB-first from u.
+    # u = q + 2^(B-1): q >= 0 <-> msb = 1, and the sign bit of 2's
+    # complement is 1 for negatives: sign_bit = 1 - msb.
+    msb = jnp.floor(u * 2.0 ** -(bits - 1))
+    sign_bit = 1.0 - msb
+    rem = u - msb * (2.0 ** (bits - 1))
+    low = []
+    for k in range(bits - 2, -1, -1):
+        w = 2.0 ** k
+        b = jnp.floor(rem * (1.0 / w))
+        rem = rem - b * w
+        low.append(b)
+    low.reverse()                                 # now LSB-first: weights 1,2,...
+    return low + [sign_bit]
+
+
 def int_to_planes(q: jax.Array, bits: int, coding: Coding) -> jax.Array:
     """Decompose integers on the coding grid into bit planes.
 
     Returns planes with values in {0,1} (AND) or {-1,+1} (XNOR), shape
     ``q.shape + (bits,)``, dtype float32 (exact small integers).
     """
-    coding = Coding(coding)
-    q = q.astype(jnp.float32)
-    if coding == Coding.XNOR:
-        if bits == 1:
-            return jnp.where(q >= 0, 1.0, -1.0)[..., None]
-        big = 2.0 ** (bits - 1)
-        u = (q + big) / 2.0                       # in [0, 2^(B-1)], integer
-        e = jnp.where(u >= big, 1.0, 0.0)         # second LSB-weight plane
-        v = u - e * 1.0
-        v = jnp.where(u >= big, big - 1.0, v)     # u == big -> v = all-ones
-        e = jnp.where(u >= big, 1.0, e)
-        # v in [0, 2^(B-1)-1]: standard binary over weights [2^(B-2) .. 1]
-        planes = []
-        rem = v
-        for k in range(bits - 2, -1, -1):
-            w = 2.0 ** k
-            b = jnp.floor(rem / w)
-            rem = rem - b * w
-            planes.append(b)
-        planes.append(e)
-        bits01 = jnp.stack(planes, axis=-1)
-        return 2.0 * bits01 - 1.0                 # {0,1} -> {-1,+1}
-    else:
-        if bits == 1:
-            return jnp.clip(q, 0, 1)[..., None]
-        # two's complement: q + 2^(B-1) = unsigned B-bit value
-        u = q + 2.0 ** (bits - 1)
-        planes = []
-        rem = u
-        # weights [1, 2, ..., 2^(B-2), -2^(B-1)]; extract MSB-first from u
-        msb = jnp.floor(rem / (2.0 ** (bits - 1)))
-        # sign plane: q < 0 <-> u < 2^(B-1) <-> msb == 0 ... careful:
-        # u = q + 2^(B-1); q >= 0 -> u >= 2^(B-1) -> msb = 1. In 2's complement
-        # the sign bit is 1 for negatives: sign_bit = 1 - msb.
-        sign_bit = 1.0 - msb
-        rem = rem - msb * (2.0 ** (bits - 1))
-        low = []
-        for k in range(bits - 2, -1, -1):
-            w = 2.0 ** k
-            b = jnp.floor(rem / w)
-            rem = rem - b * w
-            low.append(b)
-        low.reverse()                             # now LSB-first: weights 1,2,...
-        planes = low + [sign_bit]
-        return jnp.stack(planes, axis=-1)
+    return jnp.stack(int_plane_list(q, bits, coding), axis=-1)
 
 
 def planes_to_int(planes: jax.Array, bits: int, coding: Coding) -> jax.Array:

@@ -6,7 +6,20 @@ TPU-native mapping of the chip's dataflow (see DESIGN.md §2):
   charge-share/ADC boundary and (conveniently) a VMEM-sized, 128-aligned
   MXU tile (2304 = 18 * 128).  One full bank of weight bit planes at a
   256-column tile is ~590 KB of int8 — literally the chip's array size —
-  and fits VMEM with room for double buffering.
+  and fits VMEM with room for double buffering.  The column tile is the
+  array's 256 columns, or M rounded up to 128 lanes where M is narrower.
+  Where N fits one bank, the block is N rounded up to 128 lanes
+  (:func:`bank_width`): the ADC still converts one bank over its real
+  rows, and no zero lanes ride along.
+* The input arrives as its integer grid, one byte per element where the
+  grid fits int8.  The w2b Reshaping Buffer and Sparsity Controller sit
+  next to the array: from the grid's VMEM tile the kernel forms the
+  masked bit planes, the bank's unmasked-row count ``nu`` and each
+  plane's liveness for the plane-skip gate, from the same definitions
+  the fast path evaluates (:func:`repro.core.bpbs.input_plane_list`),
+  once per (row tile, bank).  Where the output spans several column
+  tiles, VMEM scratch holds them across the column tiles, as the chip's
+  reshaping buffer holds an input vector for every column it drives.
 * B_A weight bit planes are laid out in parallel in the last (lane)
   dimension, as the chip lays bit-columns side by side; B_X input planes
   stream through an in-kernel serial loop, as the chip streams input bits.
@@ -19,15 +32,14 @@ TPU-native mapping of the chip's dataflow (see DESIGN.md §2):
 
 Grid: ``(batch_tiles, column_tiles, banks)`` with the bank dimension
 innermost ("arbitrary" semantics) so output tiles accumulate in place.
-Each input and weight plane arrives as its own dense 2D block, the
-per-bank unmasked counts bank-major, and the plane liveness (plane-skip
-gate) and per-bank ADC full scales as scalar-prefetch operands in SMEM:
-every block obeys the TPU tiling rules, so the kernel compiles with
+The input grid and each weight plane arrive as dense 2D blocks, and the
+per-bank ADC full scales as a scalar-prefetch operand in SMEM (with the
+held planes, the column axis is "arbitrary" too): every
+block obeys the TPU tiling rules, so the kernel compiles with
 ``interpret=False`` at any bank count (tests/test_chip_compile.py).
 
-Inputs are int8 bit planes (HBM traffic = 1 byte/plane-element); they are
-cast to bf16 in-kernel for the MXU (values are exactly representable; f32
-accumulation of <=2304 unit products is exact).
+Planes are cast to bf16 in-kernel for the MXU (values are exactly
+representable; f32 accumulation of <=2304 unit products is exact).
 """
 from __future__ import annotations
 
@@ -40,41 +52,74 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.bpbs import BpbsConfig, gemm_adc_epilogue
+from repro.core.bpbs import BpbsConfig, gemm_adc_epilogue, input_plane_list
+from repro.core.quant import Coding, int_range
+
+LANES = 128     # TPU vector lanes: a block's minor dimension is a multiple
 
 
 def _kernel(
-    live_ref,   # SMEM [tiles*banks*BX] i32: is input plane (i, k, kx) live?
     fs_ref,     # SMEM [banks] f32: ADC full scale per bank (static gating)
-    *refs,      # BX x [bb, bank_n] int8 input planes (masked), BA x
-                # [bank_n, bm] int8 weight planes (bit-parallel), nu [bb, 1]
-                # f32 unmasked rows of this bank, then the fused epilogue's
-                # es_ref, pb_ref [1|bb, bm] f32 — then out_ref
+    x_ref,      # [bb, bank_k] input grid tile (int8 where the grid fits)
+    *refs,      # BA x [bank_k, bm] int8 weight planes (bit-parallel), then
+                # the fused epilogue's es_ref, pb_ref [1|bb, bm] f32, then
+                # out_ref, then (held planes only) the scratch xp_ref, nu_ref,
+                # live_ref
     cfg: BpbsConfig,
     wx: tuple,
     wa: tuple,
     n_banks: int = 0,
     act: str = "",
     by_bits: int = 0,
+    held: bool = False,
 ):
-    xs_refs, refs = refs[:cfg.bx], refs[cfg.bx:]
-    ws_refs, refs = refs[:cfg.ba], refs[cfg.ba:]
-    nu_ref, rest = refs[0], refs[1:]
+    ws_refs, rest = refs[:cfg.ba], refs[cfg.ba:]
+    if held:
+        rest, (xp_ref, nu_ref, live_ref) = rest[:-3], rest[-3:]
     out_ref = rest[-1]  # [bb, bm] f32: recombined output
     fused = len(rest) > 1
-    i, k = pl.program_id(0), pl.program_id(2)
+    j, k = pl.program_id(1), pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    nu = nu_ref[...]                                  # [bb, 1]
+    def _prepare():
+        # w2b reshaping buffer + sparsity controller, on this tile of the
+        # grid: the masked bit planes, the bank's unmasked-row count and
+        # each plane's liveness, from the same definitions the fast path
+        # evaluates.  Padded lanes and rows are zero: masked, so never
+        # counted.  The controller knows a plane's liveness before the
+        # evaluation fires: under XNOR every plane of an unmasked element
+        # is +-1, so a plane is live iff the tile has an unmasked element;
+        # AND planes are {0, 1}.
+        planes, mask = input_plane_list(x_ref[...].astype(jnp.float32), cfg)
+        nu = jnp.sum(mask, axis=1, keepdims=True)     # [bb, 1]
+        live = [(jnp.max(nu) if cfg.coding == Coding.XNOR
+                 else jnp.max(p)) > 0 for p in planes]
+        return planes, nu, live
+
+    if held:
+        # Wide outputs: the buffer holds the row tile's planes of every
+        # bank across the column tiles, so they are formed once per row
+        # tile (at the first column tile), not once per column tile.
+        @pl.when(j == 0)
+        def _fill():
+            planes, nu, live = _prepare()
+            nu_ref[k] = nu
+            for kx in range(cfg.bx):
+                xp_ref[k * cfg.bx + kx] = planes[kx].astype(xp_ref.dtype)
+                live_ref[k * cfg.bx + kx] = live[kx].astype(jnp.int32)
+
+        planes = [xp_ref[k * cfg.bx + kx] for kx in range(cfg.bx)]
+        nu = nu_ref[k]
+        live = [live_ref[k * cfg.bx + kx] != 0 for kx in range(cfg.bx)]
+    else:
+        planes, nu, live = _prepare()
     fs_static = fs_ref[k]
 
     acc = jnp.zeros(out_ref.shape, dtype=jnp.float32)
     for kx in range(cfg.bx):
-        xk = xs_refs[kx][...]                         # [bb, bank_n] int8
-
         def _gemms(xk):
             x = xk.astype(jnp.bfloat16)
             # mixed-signal column evaluations: one MXU pass per plane pair
@@ -92,18 +137,14 @@ def _kernel(
             # passes are gated off at runtime.  Only the (provably zero)
             # dot products are skipped; the ADC epilogue below still runs
             # on the zeros, keeping the output bit-identical to the dense
-            # path for every coding/precision.  Liveness of this tile's
-            # (bank, plane) is precomputed into SMEM (prepare step) and
-            # read here as a scalar: the controller knows the mask before
-            # the evaluation fires.
-            live = live_ref[(i * n_banks + k) * cfg.bx + kx]
+            # path for every coding/precision.
             ds = jax.lax.cond(
-                live != 0, _gemms,
+                live[kx], _gemms,
                 lambda _: tuple(jnp.zeros(out_ref.shape, jnp.float32)
                                 for _ in range(cfg.ba)),
-                xk)
+                planes[kx])
         else:
-            ds = _gemms(xk)
+            ds = _gemms(planes[kx])
         for ka in range(cfg.ba):
             # popcount recovery + SAR ADC transfer + signed-dot recovery:
             # the same epilogue definition the fast path evaluates (no
@@ -144,26 +185,40 @@ def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
+def bank_width(n: int, cfg: BpbsConfig) -> int:
+    """Lanes of one contraction block: a whole bank, or for N inside one
+    bank its rows rounded up to the 128-lane tile.  An ADC conversion
+    still covers one bank; a narrow bank just carries no zero lanes it
+    never had."""
+    return min(cfg.bank_n, -(-n // LANES) * LANES)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("cfg", "block_b", "block_m", "interpret", "act",
                      "by_bits"),
 )
 def cima_mvm_planes(
-    xs: jax.Array,          # [B, BX, N] int8 masked input planes
+    x: jax.Array,           # [B, N] integer input grid (prepare_inputs)
     ws: jax.Array,          # [N, BA, M] int8 weight planes
-    nu: jax.Array,          # [B, n_banks] f32 unmasked rows per bank
     fs: jax.Array,          # [n_banks] f32 ADC full scale per bank
     cfg: BpbsConfig,
     block_b: int = 128,
-    block_m: int = 128,
+    block_m: int = 256,
     interpret: bool = True,
     escale: Optional[jax.Array] = None,   # [M]|[B,M]|scalar: rescale*scale
     pbias: Optional[jax.Array] = None,    # [M]|scalar: datapath bias regs
     act: Optional[str] = None,
     by_bits: Optional[int] = None,
 ) -> jax.Array:
-    """Raw kernel entry on pre-decomposed planes.  Returns [B, M] f32.
+    """Raw kernel entry on the input grid and the weight planes.  Returns
+    [B, M] f32.
+
+    The input bit planes, each bank's unmasked-row count and the
+    plane-skip liveness are formed in-kernel from the grid's VMEM tile,
+    once per (row tile, bank).
+
+    ``block_m`` shrinks to M rounded up to 128 lanes where M is narrower.
 
     ``escale``/``pbias``/``act``/``by_bits`` arm the fused near-memory
     datapath epilogue (paper Fig. 8): after the last bank accumulates,
@@ -174,52 +229,34 @@ def cima_mvm_planes(
     the epilogue the kernel returns the recombined integer-grid output
     as before.
     """
-    b, bx, n = xs.shape
+    b, n = x.shape
     n_w, ba, m = ws.shape
-    assert n_w == n and bx == cfg.bx and ba == cfg.ba
+    assert n_w == n and ba == cfg.ba
     n_banks = -(-n // cfg.bank_n)
+    bank_k = bank_width(n, cfg)
+    block_m = min(block_m, -(-m // LANES) * LANES)
 
     with jax.named_scope("cima.pad"):
-        xs = _pad_to(_pad_to(xs, 0, block_b), 2, cfg.bank_n)
-        ws = _pad_to(_pad_to(ws, 0, cfg.bank_n), 2, block_m)
-        nu = _pad_to(nu, 0, block_b)
-    bp, mp = xs.shape[0], ws.shape[2]
+        x = _pad_to(_pad_to(x, 0, block_b), 1, bank_k)
+        ws = _pad_to(_pad_to(ws, 0, bank_k), 2, block_m)
+    bp, mp = x.shape[0], ws.shape[2]
 
     fused = (escale is not None or pbias is not None
              or bool(act) or bool(by_bits))
-    # scalar-prefetch operands (SMEM): per-(row tile, bank, input plane)
-    # liveness for the plane-skip gate, and the per-bank ADC full scale
-    with jax.named_scope("cima.liveness"):
-        live = jnp.any(
-            xs.reshape(bp // block_b, block_b, cfg.bx, n_banks,
-                       cfg.bank_n) != 0,
-            axis=(1, 4))                              # [tiles, BX, banks]
-        live = jnp.transpose(live, (0, 2, 1)).reshape(-1).astype(jnp.int32)
-    # Planes flatten into their trailing axis (free, row-major reshapes):
-    # input plane kx of bank k is the 2D tile (i, kx*banks + k) of
-    # [B, BX*N], weight plane ka of column tile j the tile
+    # Weight planes flatten into their trailing axis (a free, row-major
+    # reshape): plane ka of column tile j in bank k is the tile
     # (k, ka*col_tiles + j) of [N, BA*M].  One operand per plane keeps
     # every block a dense 2D tile (no strided plane slicing in-kernel).
-    # Per-bank unmasked counts go bank-major, so each block is a legal
-    # (block_b, 1) tile of a [banks, B, 1] array.
     col_tiles = mp // block_m
     with jax.named_scope("cima.planes"):
-        scalars = [live, fs.astype(jnp.float32).reshape(-1)]
-        xs2 = xs.reshape(bp, cfg.bx * n_banks * cfg.bank_n)
-        ws2 = ws.reshape(n_banks * cfg.bank_n, cfg.ba * mp)
-        operands = [xs2] * cfg.bx + [ws2] * cfg.ba + [
-            jnp.transpose(nu)[:, :, None]]
+        scalars = [fs.astype(jnp.float32).reshape(-1)]
+        operands = [x] + [ws.reshape(n_banks * bank_k, cfg.ba * mp)] * cfg.ba
     in_specs = [
-        pl.BlockSpec((block_b, cfg.bank_n),
-                     lambda i, j, k, *_, kx=kx: (i, kx * n_banks + k))
-        for kx in range(cfg.bx)
+        pl.BlockSpec((block_b, bank_k), lambda i, j, k, *_: (i, k)),
     ] + [
-        pl.BlockSpec((cfg.bank_n, block_m),
+        pl.BlockSpec((bank_k, block_m),
                      lambda i, j, k, *_, ka=ka: (k, ka * col_tiles + j))
         for ka in range(cfg.ba)
-    ] + [
-        pl.BlockSpec((pl.Squeezed(), block_b, 1),
-                     lambda i, j, k, *_: (k, i, 0)),
     ]
     if fused:
         @jax.named_scope("cima.pad")
@@ -249,7 +286,19 @@ def cima_mvm_planes(
         operands += [es, pb]
         in_specs += [vec_spec(es), vec_spec(pb)]
 
-    grid = (bp // block_b, mp // block_m, n_banks)
+    # Wider than one column tile, a row tile's planes are held in VMEM
+    # (int8: exact for {-1, 0, +1}) across the column tiles, so the column
+    # axis runs in order, and the scoped VMEM budget grows by the buffer
+    # (its `nu` column pads to a lane tile).
+    held = col_tiles > 1
+    scratch = [
+        pltpu.VMEM((n_banks * cfg.bx, block_b, bank_k), jnp.int8),
+        pltpu.VMEM((n_banks, block_b, 1), jnp.float32),
+        pltpu.SMEM((n_banks * cfg.bx,), jnp.int32),
+    ] if held else []
+    held_bytes = n_banks * block_b * (cfg.bx * bank_k + 4 * LANES)
+
+    grid = (bp // block_b, col_tiles, n_banks)
     kernel = pl.pallas_call(
         functools.partial(
             _kernel,
@@ -259,6 +308,7 @@ def cima_mvm_planes(
             n_banks=n_banks,
             act=(act or "") if fused else "",
             by_bits=(by_bits or 0) if fused else 0,
+            held=held,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
@@ -266,10 +316,14 @@ def cima_mvm_planes(
             in_specs=in_specs,
             out_specs=pl.BlockSpec((block_b, block_m),
                                    lambda i, j, k, *_: (i, j)),
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((bp, mp), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=(
+                "parallel", "arbitrary" if held else "parallel",
+                "arbitrary"),
+            vmem_limit_bytes=(32 << 20) + held_bytes if held else None,
         ),
         interpret=interpret,
         name="cima_bpbs_mvm",
@@ -280,23 +334,21 @@ def cima_mvm_planes(
         return out[:b, :m]
 
 
-def prepare_inputs(x_q: jax.Array, cfg: BpbsConfig):
-    """Input bit planes + per-bank unmasked counts (the w2b Reshaping Buffer
-    and Sparsity Controller roles, in XLA)."""
-    from repro.core.bpbs import input_planes
+def grid_dtype(cfg: BpbsConfig):
+    """The narrowest integer dtype that holds the input coding grid."""
+    lo, hi = int_range(cfg.bx, cfg.coding)
+    return jnp.int8 if -128 <= lo and hi <= 127 else jnp.int32
 
+
+def prepare_inputs(x_q: jax.Array, cfg: BpbsConfig):
+    """The kernel's input operand: the quantized grid as one 2D
+    ``[rows, N]`` array in :func:`grid_dtype`.  The w2b Reshaping Buffer
+    and Sparsity Controller roles (bit planes, per-bank unmasked counts,
+    plane liveness) run inside the kernel, next to the array."""
     lead = x_q.shape[:-1]
-    n = x_q.shape[-1]
-    n_banks = -(-n // cfg.bank_n)
-    pad = n_banks * cfg.bank_n - n
     with jax.named_scope("cima.planes"):
-        x2 = x_q.reshape(-1, n)
-        planes, mask = input_planes(x2, cfg)       # [B, N, BX], [B, N]
-        xs = jnp.transpose(planes, (0, 2, 1)).astype(jnp.int8)
-        mask_p = jnp.pad(mask, ((0, 0), (0, pad)))
-        nu = mask_p.reshape(-1, n_banks, cfg.bank_n).sum(-1).astype(
-            jnp.float32)
-    return xs, nu, lead
+        x2 = x_q.reshape(-1, x_q.shape[-1]).astype(grid_dtype(cfg))
+    return x2, lead
 
 
 def bank_full_scales(n: int, cfg: BpbsConfig) -> jax.Array:
@@ -329,7 +381,7 @@ def cima_mvm(
     w_q: jax.Array,
     cfg: BpbsConfig,
     block_b: int = 128,
-    block_m: int = 128,
+    block_m: int = 256,
     interpret: bool = True,
     escale: Optional[jax.Array] = None,
     pbias: Optional[jax.Array] = None,
@@ -339,9 +391,9 @@ def cima_mvm(
     """BP/BS MVM on integer-grid operands: [..., N] x [N, M] -> [..., M].
     ``escale``/``pbias``/``act``/``by_bits`` arm the fused datapath
     epilogue (see :func:`cima_mvm_planes`)."""
-    xs, nu, lead = prepare_inputs(x_q, cfg)
+    x2, lead = prepare_inputs(x_q, cfg)
     ws, fs = prepare_weights(w_q, cfg)
-    y = cima_mvm_planes(xs, ws, nu, fs, cfg, block_b, block_m, interpret,
+    y = cima_mvm_planes(x2, ws, fs, cfg, block_b, block_m, interpret,
                         escale, pbias, act, by_bits)
     with jax.named_scope("cima.post"):
         return y.reshape(*lead, w_q.shape[1])
@@ -352,7 +404,7 @@ def cima_mvm_from_planes(
     ws: jax.Array,                # [N, BA, M] int8 weight bit planes
     cfg: BpbsConfig,
     block_b: int = 128,
-    block_m: int = 128,
+    block_m: int = 256,
     interpret: bool = True,
     escale: Optional[jax.Array] = None,
     pbias: Optional[jax.Array] = None,
@@ -361,10 +413,11 @@ def cima_mvm_from_planes(
 ) -> jax.Array:
     """BP/BS MVM consuming a pre-compiled weight image: the weight-
     stationary serving path.  Only the (dynamic) inputs are decomposed
-    per call; the planes come straight from the loaded program."""
-    xs, nu, lead = prepare_inputs(x_q, cfg)
+    per call, inside the kernel; the weight planes come straight from
+    the loaded program."""
+    x2, lead = prepare_inputs(x_q, cfg)
     fs = bank_full_scales(ws.shape[0], cfg)
-    y = cima_mvm_planes(xs, ws, nu, fs, cfg, block_b, block_m, interpret,
+    y = cima_mvm_planes(x2, ws, fs, cfg, block_b, block_m, interpret,
                         escale, pbias, act, by_bits)
     with jax.named_scope("cima.post"):
         return y.reshape(*lead, ws.shape[2])

@@ -25,7 +25,7 @@ def cima_mvm(
     w_q: jax.Array,
     cfg: BpbsConfig,
     block_b: int = 128,
-    block_m: int = 128,
+    block_m: int = 256,
     interpret: Optional[bool] = None,
     escale: Optional[jax.Array] = None,
     pbias: Optional[jax.Array] = None,
@@ -46,7 +46,7 @@ def cima_mvm_from_planes(
     ws: jax.Array,
     cfg: BpbsConfig,
     block_b: int = 128,
-    block_m: int = 128,
+    block_m: int = 256,
     interpret: Optional[bool] = None,
     escale: Optional[jax.Array] = None,
     pbias: Optional[jax.Array] = None,

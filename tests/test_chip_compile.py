@@ -2,7 +2,9 @@
 
 Nothing runs: each case lowers ``cima_bpbs_mvm`` with ``interpret=False``
 and compiles it with the TPU compiler for one chip of a described
-``v5e:2x2`` topology, at olmo-1b's published widths and B_A = B_X = 4.
+``v5e:2x2`` topology, at olmo-1b's published widths and B_A = B_X = 4,
+and at CIFAR Network B's (B_A = B_X = 1: one narrow 128-lane bank, a
+1152-lane bank, a full 2304-row bank).
 That is what refuses a kernel that interpret mode accepts (illegal block
 tiling, layouts Mosaic cannot relayout, VMEM overuse).  The topology is
 described inside a fixture, so where no TPU compiler is installed these
@@ -53,37 +55,57 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def _compile(one_chip, n, m, fused):
+def _compile(one_chip, cfg, rows, n, m, fused):
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    args = [arg((DECODE_ROWS, n), jnp.int8),          # quantized inputs
-            arg((n, CFG.ba, m), jnp.int8)]            # CimaImage.ws planes
-    if fused:
+    args = [arg((rows, n), jnp.int8),                 # quantized inputs
+            arg((n, cfg.ba, m), jnp.int8)]            # CimaImage.ws planes
+    if fused == "row_silu":
         # per-row escale: batch-decoupled input scales folded into the
         # datapath scale registers, then silu and B_y = 32 saturation
-        args.append(arg((DECODE_ROWS, m), jnp.float32))
+        args.append(arg((rows, m), jnp.float32))
 
         def fn(x, ws, es):
-            return cima_mvm_from_planes(x, ws, CFG, interpret=False,
+            return cima_mvm_from_planes(x, ws, cfg, interpret=False,
                                         escale=es, act="silu", by_bits=32)
+    elif fused == "col_sign":
+        # per-column escale (folded batch-norm), then the ABN's sign
+        args.append(arg((m,), jnp.float32))
+
+        def fn(x, ws, es):
+            return cima_mvm_from_planes(x, ws, cfg, interpret=False,
+                                        escale=es, act="sign", by_bits=2)
     else:
         def fn(x, ws):
-            return cima_mvm_from_planes(x, ws, CFG, interpret=False)
+            return cima_mvm_from_planes(x, ws, cfg, interpret=False)
 
     compiled = jax.jit(fn).lower(*args).compile()
     want = sum(a.size * a.dtype.itemsize for a in args)
     return compiled, want
 
 
-@pytest.mark.parametrize("n,m,fused", [
-    (2048, 8192, False),     # one bank: attention / mlp.gate, mlp.up
-    (8192, 2048, False),     # four banks: mlp.down (per-bank nu, fs)
-    (2048, 8192, True),      # fused epilogue with per-row escale + silu
-], ids=["single_bank", "multi_bank", "fused_silu_row_escale"])
-def test_cima_mvm_compiles_for_v5e(one_chip, no_compile_cache, n, m, fused):
-    assert CFG.skip_zero_planes
-    compiled, want = _compile(one_chip, n, m, fused)
-    assert compiled.memory_analysis().argument_size_in_bytes == want
+NET_B = BpbsConfig(ba=1, bx=1)        # XNOR, as cifar-net-b runs
+
+
+@pytest.mark.parametrize("cfg,rows,n,m,fused", [
+    (CFG, DECODE_ROWS, 2048, 8192, None),      # one bank: attention /
+                                               # mlp.gate, mlp.up
+    (CFG, DECODE_ROWS, 8192, 2048, None),      # four banks: mlp.down
+                                               # (per-bank nu, fs)
+    (CFG, DECODE_ROWS, 2048, 8192, "row_silu"),
+    (NET_B, 4096, 27, 128, "col_sign"),        # conv 0: 128-lane block
+    (NET_B, 4096, 1152, 128, "col_sign"),      # conv 1
+    (NET_B, 4096, 1152, 256, None),            # conv 2
+    (NET_B, 4096, 2304, 256, "col_sign"),      # conv 3-5: a whole bank
+], ids=["single_bank", "multi_bank", "fused_silu_row_escale",
+        "netb_n27", "netb_n1152", "netb_n1152_m256", "netb_n2304"])
+def test_cima_mvm_compiles_for_v5e(one_chip, no_compile_cache, cfg, rows,
+                                   n, m, fused):
+    assert cfg.skip_zero_planes
+    compiled, want = _compile(one_chip, cfg, rows, n, m, fused)
+    size = compiled.memory_analysis().argument_size_in_bytes
+    # a 27-wide operand is laid out padded to its tile
+    assert size == want if n % 128 == 0 else want < size < 2 * want
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "cima_bpbs_mvm" in text

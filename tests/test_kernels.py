@@ -7,6 +7,7 @@ import pytest
 from hyp_compat import given, settings, st
 
 from repro.core.bpbs import BpbsConfig
+from repro.core.datapath import postreduce
 from repro.core.quant import Coding, int_range
 from repro.kernels import ops, ref
 
@@ -30,9 +31,10 @@ def _ops(coding, ba, bx, n, m, batch, sparsity=0.3, seed=0):
     return jnp.asarray(x, jnp.float32), jnp.asarray(w, jnp.float32)
 
 
-# tier-1 keeps one XNOR and one AND case; the full shape sweep is `slow`
+# tier-1 keeps one XNOR and one AND case of the shape sweep (the rest is
+# `slow`), and the input-grid cases below it
 CIMA_CASES = [
-    # (coding, ba, bx, n, m, bank_n, block_b, block_m, fast)
+    # (coding, ba, bx, n, m, bank_n, block_b, block_m, fast[, extra])
     (Coding.XNOR, 4, 4, 300, 40, 2304, 8, 16, True),
     (Coding.XNOR, 1, 1, 256, 32, 2304, 16, 32, False),
     (Coding.XNOR, 2, 3, 512, 16, 256, 8, 16, False),   # multi-bank + padding
@@ -41,19 +43,61 @@ CIMA_CASES = [
     (Coding.AND, 4, 4, 300, 40, 2304, 8, 16, False),
     (Coding.AND, 2, 2, 512, 16, 128, 8, 16, True),
     (Coding.AND, 6, 3, 700, 12, 512, 4, 4, False),
+    # The kernel's operand is the integer input grid; planes, per-bank
+    # unmasked counts and plane liveness are formed in VMEM.  Network B's
+    # narrow bank (27 rows in a 128-lane block), its 1152- and 2304-row
+    # banks, a bank plus one row and a ragged third bank, at B_A = B_X in
+    # {1, 4}.  "zero_tile": zero elements in every row (mask and nu) and an
+    # all-zero second row tile (the plane-skip gate); "fused": the datapath
+    # epilogue.  M = 24 over 8-column tiles holds a row tile's planes
+    # across the column tiles.
+    (Coding.XNOR, 1, 1, 27, 24, 2304, 8, 8, True, "zero_tile"),
+    (Coding.XNOR, 1, 1, 1152, 16, 2304, 8, 16, True, "zero_tile-fused"),
+    (Coding.XNOR, 1, 1, 2304, 16, 2304, 8, 16, True, "zero_tile-fused"),
+    (Coding.XNOR, 1, 1, 2305, 24, 2304, 8, 8, True, "zero_tile-fused"),
+    (Coding.XNOR, 4, 4, 27, 16, 2304, 8, 16, True, "zero_tile-fused"),
+    (Coding.XNOR, 4, 4, 1152, 24, 2304, 8, 8, True, "zero_tile"),
+    (Coding.XNOR, 4, 4, 2305, 16, 2304, 8, 16, True, "zero_tile"),
+    (Coding.AND, 1, 1, 27, 16, 2304, 8, 16, True, "zero_tile-fused"),
+    (Coding.AND, 1, 1, 1152, 24, 2304, 8, 8, True, "zero_tile"),
+    (Coding.AND, 1, 1, 2305, 16, 2304, 8, 16, True, "zero_tile-fused"),
+    (Coding.AND, 4, 4, 27, 24, 2304, 8, 8, True, "zero_tile"),
+    (Coding.AND, 4, 4, 1152, 16, 2304, 8, 16, True, "zero_tile-fused"),
+    (Coding.AND, 4, 4, 2305, 24, 2304, 8, 8, True, "zero_tile-fused"),
+    (Coding.AND, 4, 4, 4700, 24, 2304, 8, 8, True, "zero_tile"),
 ]
 
 
-@pytest.mark.parametrize(
-    "coding,ba,bx,n,m,bank_n,bb,bm",
-    [pytest.param(*c[:8], marks=[] if c[8] else pytest.mark.slow)
-     for c in CIMA_CASES])
-def test_cima_mvm_matches_oracle(coding, ba, bx, n, m, bank_n, bb, bm):
-    x, w = _ops(coding, ba, bx, n, m, batch=5)
+def _cima_param(coding, ba, bx, n, m, bank_n, bb, bm, fast, extra=""):
+    case = (coding, ba, bx, n, m, bank_n, bb, bm, extra)
+    name = "-".join(str(v) for v in (coding.value,) + case[1:] if v != "")
+    return pytest.param(*case, id=name,
+                        marks=[] if fast else pytest.mark.slow)
+
+
+@pytest.mark.parametrize("coding,ba,bx,n,m,bank_n,bb,bm,extra",
+                         [_cima_param(*c) for c in CIMA_CASES])
+def test_cima_mvm_matches_oracle(coding, ba, bx, n, m, bank_n, bb, bm,
+                                 extra):
+    zero_tile = "zero_tile" in extra
+    x, w = _ops(coding, ba, bx, n, m, batch=2 * bb if zero_tile else 5)
     cfg = BpbsConfig(ba=ba, bx=bx, coding=coding, bank_n=bank_n)
-    y_k = ops.cima_mvm(x, w, cfg, block_b=bb, block_m=bm)
-    y_r = ref.cima_mvm_ref(x, w, cfg)
-    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r), atol=1e-3)
+    r = np.random.default_rng(n)
+    if zero_tile:
+        x = x * jnp.asarray(r.random(x.shape) > 0.2)  # zeros in every coding
+        x = x.at[bb:].set(0.0)
+    y_r = ref.cima_mvm_ref(x, w, cfg)                 # the bpbs fast path
+    epi = {}
+    if "fused" in extra:
+        # powers of two and quarters keep the reference's epilogue exact
+        epi = dict(escale=jnp.asarray(2.0 ** r.integers(-3, 2, m),
+                                      jnp.float32),
+                   pbias=jnp.asarray(r.integers(-8, 8, m) / 4, jnp.float32),
+                   act="sign" if bx == 1 else "relu", by_bits=8)
+        y_r = postreduce(y_r, scale=epi["escale"], bias=epi["pbias"],
+                         act=epi["act"], by_bits=epi["by_bits"])
+    y_k = ops.cima_mvm(x, w, cfg, block_b=bb, block_m=bm, **epi)
+    np.testing.assert_array_equal(np.asarray(y_k), np.asarray(y_r))
 
 
 @pytest.mark.slow

@@ -23,8 +23,9 @@ CELL = "cifar-net-b.batch256"
 
 NO_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
            "after-all"}
+# the plane-skip liveness is formed inside the kernel, under cima.kernel
 KERNEL_PATH = {"cima.quantize_x", "cima.quantize_w", "cima.planes",
-               "cima.pad", "cima.liveness", "cima.kernel", "cima.post"}
+               "cima.pad", "cima.kernel", "cima.post"}
 
 
 def _net_config(backend, layers):
