@@ -19,10 +19,13 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.bpbs import (bpbs_matmul_planes, bpbs_matmul_planes_reference,
                              weight_planes)
-from repro.core.quant import QTensor, quantize
+from repro.core.quant import (EPS, QTensor, grid_scale, quantize, to_grid,
+                              uses_mean)
+from repro.core.w2b import im2col, multiplicity
 
 from .context import ExecContext
 from .registry import register_backend
@@ -47,6 +50,40 @@ def quantize_input(x: jax.Array, spec: ExecSpec) -> QTensor:
         qx = quantize(x, spec.bx, spec.coding, per_row=spec.x_per_row)
         q_int = cs(qx.q.astype(jnp.int8), ("dp",))
         return dataclasses.replace(qx, q=q_int)
+
+
+def quantize_windows(x: jax.Array, spec: ExecSpec, k: int) -> QTensor:
+    """The per-tensor input grid of ``im2col(x, k)``, formed from the
+    pixels ``x`` [B, H, W, C]: each pixel quantized and cast to int8
+    once, then the ``k*k`` windows written on the int8 grid, padded with
+    the grid's value of 0.
+
+    The grid is an elementwise function of the pixel and one scale, and
+    the scale comes from the pixels: ``max|x|`` is the windows' (every
+    pixel lies in a window, the padding adds 0), and ``E|x|`` over the
+    windows (1-b XNOR) is the sum of ``|x|`` weighted by how many windows
+    hold each pixel, over the windows' element count.  So this is
+    :func:`quantize_input` of the float windows bit for bit, except that
+    the 1-b mean is summed in another order (its last ulp may differ).
+    A per-row scale would be per window: it has no such form.
+    """
+    from repro.distributed.autoshard import cs
+
+    b, h, w, c = x.shape
+    bits, coding = spec.bx, spec.coding
+    with jax.named_scope("cima.quantize_x"):
+        ax = jnp.abs(x)
+        if uses_mean(bits, coding):
+            per_pixel = jnp.sum(ax, axis=(0, 3))                  # [H, W]
+            stat = (jnp.sum(per_pixel * multiplicity(h, w, k))
+                    / (b * h * w * k * k * c))
+        else:
+            stat = jnp.max(ax)
+        scale = grid_scale(jnp.maximum(stat, EPS), bits)
+        q = to_grid(x, scale, bits, coding).astype(jnp.int8)
+        with jax.ensure_compile_time_eval():
+            zero = int(to_grid(np.float32(0), np.float32(1), bits, coding))
+    return QTensor(cs(im2col(q, k, zero), ("dp",)), scale, bits, coding)
 
 
 def weight_grid(w: jax.Array, spec: ExecSpec,
@@ -180,10 +217,13 @@ def pallas(x: jax.Array, w: jax.Array, spec: ExecSpec,
     """The Pallas TPU kernel (interpret mode on CPU unless overridden).
     A per-column ``ctx.post`` fuses into the kernel's datapath epilogue:
     the quantization rescale folds into the scale registers and the
-    output leaves the kernel already post-reduced."""
+    output leaves the kernel already post-reduced.  With ``ctx.window``
+    set, ``x`` is a layer's pixels and the windows are formed on the int8
+    grid (:func:`quantize_windows`)."""
     from repro.kernels import ops as kernel_ops
 
-    qx = quantize_input(x, spec)
+    qx = (quantize_windows(x, spec, ctx.window) if ctx.window
+          else quantize_input(x, spec))
     img = ctx.image
     if img is not None:
         ws_planes, w_scale = img.ws, img.scale

@@ -38,6 +38,10 @@ class ExecContext:
     # the recombined output before returning — the chip's post-reduce
     # pipeline, with no HBM round-trip between reduce and post-ops
     post: Optional[object] = None       # core.datapath.Postreduce | None
+    # conv window size: when set, ``x`` is a layer's pixels [B, H, W, C]
+    # and the backend forms the k x k windows after quantizing, on the
+    # integer input grid (the dispatcher sets it for backends that do)
+    window: Optional[int] = None
 
 
 # ------------------------------------------------------------- overrides
@@ -165,6 +169,10 @@ class MvmRecord:
     # (loads-if-streamed == copies, exactly what the traced ``loads``
     # equals whenever the image actually streamed).
     copies: int = 1
+    # 1 where this conv dispatch formed its windows on the integer input
+    # grid (quantized per pixel, then windowed), 0 where it was handed
+    # float windows or is no conv
+    grid_windows: int = 0
 
 
 class Trace(list):

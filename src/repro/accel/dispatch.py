@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.sanitize import active as _san_active
+from repro.core.w2b import im2col
 
 from .context import (ExecContext, MvmRecord, current_override,
                       current_pad_mask, next_noise_key, record,
@@ -98,9 +99,11 @@ def _measured_planes(spec: ExecSpec, x: jax.Array) \
 
 
 def _record_mvm(spec: ExecSpec, x: jax.Array, w: jax.Array,
-                image=None, post=None) -> None:
+                image=None, post=None, window=None) -> None:
     if not tracing():
         return
+    if window and not isinstance(x, jax.core.Tracer):
+        x = im2col(x, window)       # the rows the eager measurements see
     streamed = image is not None and not image.resident
     overlap = streamed and getattr(image, "overlap", False)
     # double-buffer prologue: the first streamed load of a pass has no
@@ -133,7 +136,28 @@ def _record_mvm(spec: ExecSpec, x: jax.Array, w: jax.Array,
         sparsity=_measured_sparsity(spec, x),
         planes_skipped=skipped,
         planes_total=total,
+        grid_windows=1 if window else 0,
     ))
+
+
+def _windows_on_grid(spec: ExecSpec, mesh) -> bool:
+    """Does a conv dispatch hand the backend its pixels, to form the
+    windows after quantizing, on the int8 grid?  The ``pallas`` kernel's
+    operand is that grid; a per-tensor input scale is a function of the
+    pixels (a per-row scale is one per window); the mesh path takes
+    rows."""
+    return spec.backend == "pallas" and not spec.x_per_row and mesh is None
+
+
+def _ste_grads(x, w, g, window):
+    """``(dx, dw)`` of the plain float GEMM (the STE), through the
+    windows where ``x`` is a layer's pixels."""
+    if window:
+        h, unwindow = jax.vjp(lambda x_: im2col(x_, window), x)
+    else:
+        h, unwindow = x, lambda d: (d,)
+    dx, = unwindow(jnp.einsum("...m,nm->...n", g, w))
+    return dx, jnp.einsum("...n,...m->nm", h, g)
 
 
 def _shard_mesh(image):
@@ -165,6 +189,7 @@ def matmul(
     dtype=None,
     image=None,
     post=None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """``x @ w`` under ``spec``'s execution backend.
 
@@ -190,8 +215,17 @@ def matmul(
       gradients are exactly the unfused composition's: STE through the
       quantized matmul, the true VJP through the epilogue (including
       cotangents for ``post.scale``/``post.bias``).
+    * ``window`` (optional, ``k``): ``x`` is a layer's pixels
+      [B, H, W, C] and the product is the ``k x k`` SAME convolution,
+      ``im2col(x, k) @ w`` (:func:`repro.core.w2b.im2col`), recorded as
+      that MVM.  On ``pallas`` at a per-tensor input scale the backend
+      quantizes the pixels and writes the windows on the int8 grid;
+      otherwise they are formed here, on the float activations.  STE
+      gradients either way.
     """
     if spec is None:
+        if window:
+            x = im2col(x, window)
         dt = dtype or x.dtype
         y = jnp.einsum("...n,nm->...m", x.astype(dt), w.astype(dt))
         return post.apply(y) if post is not None else y
@@ -205,7 +239,9 @@ def matmul(
     if image is not None and not image_matches(image, spec, w):
         image = None
     mesh = _shard_mesh(image)
-    _record_mvm(spec, x, w, image, post)
+    if window and not _windows_on_grid(spec, mesh):
+        x, window = im2col(x, window), None
+    _record_mvm(spec, x, w, image, post, window)
 
     if mesh is not None:
         # mesh-partitioned program path: the backend runs under shard_map,
@@ -223,6 +259,8 @@ def matmul(
         ctx = ExecContext(key=next_noise_key())
     if image is not None:
         ctx = dataclasses.replace(ctx, image=image)
+    if window:
+        ctx = dataclasses.replace(ctx, window=window)
     san = _san_active()
     if san is not None:
         where = spec.tag or spec.backend
@@ -251,9 +289,7 @@ def matmul(
 
         def _bwd(res, g):
             x, w = res
-            dx = jnp.einsum("...m,nm->...n", g, w)
-            dw = jnp.einsum("...n,...m->nm", x, g)
-            return dx, dw
+            return _ste_grads(x, w, g, window)
 
         _op.defvjp(_fwd, _bwd)
         return _guard_out(_op(xf, wf), spec)
@@ -280,9 +316,7 @@ def matmul(
         x, w, y_pre, pa = res
         _, pvjp = jax.vjp(_epi, y_pre, *pa)
         gy, *gpa = pvjp(g)
-        dx = jnp.einsum("...m,nm->...n", gy, w)
-        dw = jnp.einsum("...n,...m->nm", x, gy)
-        return (dx, dw, *gpa)
+        return (*_ste_grads(x, w, gy, window), *gpa)
 
     _opf.defvjp(_fwd, _bwd)
     return _guard_out(_opf(xf, wf, *pargs), spec)

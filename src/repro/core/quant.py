@@ -31,6 +31,9 @@ import jax.numpy as jnp
 import numpy as np
 
 
+EPS = 1e-12       # floor of a grid's range statistic (an all-zero operand)
+
+
 class Coding(str, enum.Enum):
     XNOR = "xnor"
     AND = "and"
@@ -92,7 +95,7 @@ def quantize(
     bits: int,
     coding: Coding,
     axis: Optional[int] = None,
-    eps: float = 1e-12,
+    eps: float = EPS,
     per_row: bool = False,
 ) -> QTensor:
     """Symmetric (per-tensor, per-axis, or per-row) quantization onto the
@@ -117,28 +120,41 @@ def quantize(
         reduce_axes = tuple(i for i in range(x.ndim) if i != axis)
         return fn(jnp.abs(x), axis=reduce_axes, keepdims=True)
 
-    amax = jnp.maximum(_reduce(jnp.max), eps)
+    stat = jnp.mean if uses_mean(bits, coding) else jnp.max
+    scale = grid_scale(jnp.maximum(_reduce(stat), eps), bits)
+    return QTensor(to_grid(x, scale, bits, coding), scale, bits, coding)
 
+
+def uses_mean(bits: int, coding: Coding) -> bool:
+    """Does the grid's scale come from ``E|x|`` (1-b XNOR, BNN-style: the
+    sign at the mean magnitude) rather than from ``max|x|``?"""
+    return Coding(coding) == Coding.XNOR and bits == 1
+
+
+def grid_scale(stat: jax.Array, bits: int) -> jax.Array:
+    """The scale of a ``bits``-wide grid from its range statistic
+    (:func:`uses_mean` says which): the statistic itself at one bit, else
+    ``max|x| / 2^(B-1)`` — the top of the XNOR even-integer grid
+    (``q = 2 * level``, level in ``[-2^(B-2), 2^(B-2)]``) and the
+    magnitude of the most negative two's-complement code."""
+    return stat if bits == 1 else stat / 2.0 ** (bits - 1)
+
+
+def to_grid(x: jax.Array, scale: jax.Array, bits: int,
+            coding: Coding) -> jax.Array:
+    """``x`` onto the coding grid at ``scale``: the ``q`` of
+    :func:`quantize`, elementwise in ``x`` for a given scale."""
+    coding = Coding(coding)
     if coding == Coding.XNOR:
         if bits == 1:
-            # BNN-style: q in {-1, +1}; scale = E|x| preserves magnitude.
-            scale = jnp.maximum(_reduce(jnp.mean), eps)
-            q = jnp.where(x >= 0, 1.0, -1.0)
-            return QTensor(q, scale, bits, coding)
+            return jnp.where(x >= 0, 1.0, -1.0)
         half = 2.0 ** (bits - 2)          # max level index
-        scale = amax / (2.0 * half)       # q = 2 * level, level in [-half, half]
         level = jnp.clip(jnp.round(x / (2.0 * scale)), -half, half)
-        return QTensor(2.0 * level, scale, bits, coding)
-    else:
-        if bits == 1:
-            scale = amax
-            q = jnp.clip(jnp.round(x / scale), 0, 1)
-            return QTensor(q, scale, bits, coding)
-        qmax = 2.0 ** (bits - 1) - 1
-        qmin = -(2.0 ** (bits - 1))
-        scale = amax / (2.0 ** (bits - 1))
-        q = jnp.clip(jnp.round(x / scale), qmin, qmax)
-        return QTensor(q, scale, bits, coding)
+        return 2.0 * level
+    if bits == 1:
+        return jnp.clip(jnp.round(x / scale), 0, 1)
+    return jnp.clip(jnp.round(x / scale), -(2.0 ** (bits - 1)),
+                    2.0 ** (bits - 1) - 1)
 
 
 def int_plane_list(q: jax.Array, bits: int, coding: Coding) -> list:

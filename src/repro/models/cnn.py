@@ -1,9 +1,9 @@
 """The paper's CIFAR-10 networks (Fig. 11), mapped exactly as the chip maps
-them: every 3x3 conv is im2col'd into an MVM of dimensionality
-N = 9*C_in (<= 2304 = 3*3*256, the CIMA's designed-for shape) and executed
-through the CIMU; batch-norm folds into the near-memory datapath's
-scale/bias registers; Network B's binary activations are the ABN
-comparator.
+them: every 3x3 conv is im2col'd (:mod:`repro.core.w2b`) into an MVM of
+dimensionality N = 9*C_in (<= 2304 = 3*3*256, the CIMA's designed-for
+shape) and executed through the CIMU; batch-norm folds into the
+near-memory datapath's scale/bias registers; Network B's binary
+activations are the ABN comparator.
 
 Inference runs the chip's own pipeline (DESIGN.md §10): the BN **running
 statistics** fold through :func:`repro.core.datapath.fold_batchnorm` into
@@ -25,29 +25,10 @@ import jax.numpy as jnp
 from repro import accel
 from repro.configs.cifar_nets import CnnConfig
 from repro.core.datapath import Postreduce, fold_batchnorm
+from repro.core.w2b import im2col
 from repro.optim.qat import ste_sign
 
 from .layers import truncated_normal_init
-
-
-def _im2col(x: jax.Array, k: int = 3) -> jax.Array:
-    """x: [B, H, W, C] -> patches [B, H, W, k*k*C] (SAME padding) — the
-    w2b Reshaping Buffer's window extraction (Fig. 6a).
-
-    The patch axis is SPATIAL-major: row ``(kh*k + kw)*C + c`` holds
-    input channel ``c`` at window offset ``(kh, kw)`` — the chip's
-    ``9*C_in`` CIMA row order, so exported weight matrices map onto the
-    array deterministically.  (``conv_general_dilated_patches`` itself
-    returns the CHANNEL-major ``[..., C*k*k]`` ordering — ``(c, kh,
-    kw)`` — so the patches are transposed here; the old code returned
-    that raw layout while the docstring claimed ``9*C``.)
-    """
-    b, h, w, c = x.shape
-    patches = jax.lax.conv_general_dilated_patches(
-        x, (k, k), (1, 1), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))   # [B, H, W, C*k*k]
-    patches = patches.reshape(b, h, w, c, k * k)
-    return jnp.swapaxes(patches, -1, -2).reshape(b, h, w, k * k * c)
 
 
 def init_cnn(key, net: CnnConfig) -> dict:
@@ -95,13 +76,19 @@ def update_bn_stats(params: dict, stats, momentum: float = 0.9) -> dict:
 
 def _layer(x, i: int, layer, p: dict, net: CnnConfig, train: bool,
            bn_stats: list):
-    """One layer of :func:`cnn_forward`: im2col (or flatten), the CIMA
-    matmul with its datapath epilogue, and the optional pool."""
-    with jax.named_scope("cnn.im2col"):
-        if layer.kind == "conv":
-            h = _im2col(x)                               # [B,H,W,9*Cin]
-        else:
-            h = x.reshape(x.shape[0], -1)                # flatten
+    """One layer of :func:`cnn_forward`: the CIMA matmul of the 3x3
+    windows (or the flattened activations) with its datapath epilogue,
+    and the optional pool.
+
+    At inference a conv layer hands ``accel.matmul`` its pixels with
+    ``window=3``: where the spec allows, the windows are formed after
+    the input quantizer, on the int8 grid.  Training takes the float
+    windows (:func:`~repro.core.w2b.im2col`) as its STE operand."""
+    if layer.kind == "conv":
+        h, window = (im2col(x), None) if train else (x, 3)
+    else:
+        with jax.named_scope("cnn.im2col"):
+            h, window = x.reshape(x.shape[0], -1), None  # flatten
     spec = net.policy.resolve(f"layer{i}", kind=layer.kind, layer=i)
     last = i == len(net.layers) - 1
     if train:
@@ -118,7 +105,8 @@ def _layer(x, i: int, layer, p: dict, net: CnnConfig, train: bool,
             act=None if last else
             ("sign" if net.readout == "abn" else "relu"),
             saturate=True)
-        y = accel.matmul(h, p["w"], spec, dtype=jnp.float32, post=post)
+        y = accel.matmul(h, p["w"], spec, dtype=jnp.float32, post=post,
+                         window=window)
     if layer.kind == "conv" and layer.pool:
         with jax.named_scope("cnn.pool"):
             b_, hh, ww, c = y.shape

@@ -6,14 +6,19 @@ and compiles it with the TPU compiler for one chip of a described
 and at CIFAR Network B's (B_A = B_X = 1: one narrow 128-lane bank, a
 1152-lane bank, a full 2304-row bank).
 That is what refuses a kernel that interpret mode accepts (illegal block
-tiling, layouts Mosaic cannot relayout, VMEM overuse).  The topology is
-described inside a fixture, so where no TPU compiler is installed these
-tests skip, and importing this file touches no device state.
+tiling, layouts Mosaic cannot relayout, VMEM overuse).  Network B's conv
+layers are also compiled whole, through ``accel.matmul(window=3)`` at
+batch 256, to read what XLA makes of the windows on the int8 grid.  The
+topology is described inside a fixture, so where no TPU compiler is
+installed these tests skip, and importing this file touches no device
+state.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -109,3 +114,63 @@ def test_cima_mvm_compiles_for_v5e(one_chip, no_compile_cache, cfg, rows,
     assert size == want if n % 128 == 0 else want < size < 2 * want
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "cima_bpbs_mvm" in text
+
+
+# ``%name = <shape> <opcode>(operands...)`` of a compiled module's text
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (\S+) ([\w-]+)\((.*)$")
+
+
+def _instructions(text):
+    out = {}
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m:
+            out[m.group(1)] = (m.group(2), m.group(3),
+                               re.findall(r"%([\w.\-]+)", m.group(4)))
+    return out
+
+
+def _elements(shape):
+    dims = shape.split("[", 1)[1].split("]", 1)[0]
+    return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+
+@pytest.mark.parametrize("hw,cin,cout", [(32, 128, 128), (16, 256, 256)],
+                         ids=["netb_conv1", "netb_conv3"])
+def test_netb_conv_windows_on_the_grid_compile_for_v5e(
+        one_chip, no_compile_cache, hw, cin, cout):
+    """One Network B conv layer at batch 256, fused sign epilogue: no
+    convolution, no float windows, no int8 relayout copy of a rank-5
+    window tensor, and the kernel's ``s8[rows, 9*C]`` operand written by
+    a fusion (through bitcasts only)."""
+    from repro import accel
+
+    spec = accel.ExecSpec(backend="pallas", ba=1, bx=1, interpret=False)
+
+    def fn(x, w, s, b):
+        post = accel.Postreduce(scale=s, bias=b, act="sign", saturate=True)
+        return accel.matmul(x, w, spec, post=post, window=3)
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    text = jax.jit(fn).lower(arg((256, hw, hw, cin)), arg((9 * cin, cout)),
+                             arg((cout,)), arg((cout,))).compile().as_text()
+    assert "convolution(" not in text
+    ins = _instructions(text)
+    relayouts = [n for n, (shape, op, _) in ins.items()
+                 if op == "copy" and shape.startswith("s8[")
+                 and shape.split("]")[0].count(",") == 4]
+    assert not relayouts, relayouts
+    windows = 256 * hw * hw * 9 * cin
+    f32_windows = [n for n, (shape, _, _) in ins.items()
+                   if shape.startswith("f32[") and _elements(shape) == windows]
+    assert not f32_windows, f32_windows
+    operand = f"s8[{256 * hw * hw},{9 * cin}]"
+    (kernel,) = [n for n, (_, op, _) in ins.items()
+                 if op == "custom-call" and n.startswith("cima_bpbs_mvm")]
+    (producer,) = [a for a in ins[kernel][2]
+                   if ins[a][0].startswith(operand)]
+    while ins[producer][1] == "bitcast":
+        producer = ins[producer][2][0]
+    assert ins[producer][1] == "fusion", ins[producer]

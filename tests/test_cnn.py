@@ -1,13 +1,18 @@
-"""Fig. 11 CNN correctness: im2col row order, inference BN on running
-statistics through the fused datapath, and training-stat maintenance."""
+"""Fig. 11 CNN correctness: im2col row order, the windows formed on the
+int8 input grid, inference BN on running statistics through the fused
+datapath, and training-stat maintenance."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro import accel
+from repro.accel.backends import quantize_input, quantize_windows
 from repro.configs.cifar_nets import NETWORK_A, NETWORK_B
-from repro.models.cnn import (_im2col, cnn_forward, cnn_loss, init_cnn,
-                              update_bn_stats)
+from repro.core.w2b import im2col, multiplicity
+from repro.models.cnn import cnn_forward, cnn_loss, init_cnn, update_bn_stats
 
 KEY = jax.random.PRNGKey(0)
 
@@ -15,11 +20,11 @@ KEY = jax.random.PRNGKey(0)
 def test_im2col_is_spatial_major_9xC():
     """Patch row (kh*k + kw)*C + c must hold channel c at window offset
     (kh, kw) — the chip's 9*C_in CIMA row order.  (The raw
-    conv_general_dilated_patches output is CHANNEL-major C*k*k; the old
+    conv_general_dilated_patches output is CHANNEL-major C*k*k; older
     code returned that while claiming 9*C.)"""
     x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 5, 5, 3)),
                     jnp.float32)
-    p = np.asarray(_im2col(x, k=3))
+    p = np.asarray(im2col(x, k=3))
     assert p.shape == (2, 5, 5, 27)
     xp = np.pad(np.asarray(x), ((0, 0), (1, 1), (1, 1), (0, 0)))  # SAME
     for (b, i, j) in [(0, 0, 0), (0, 2, 3), (1, 4, 4)]:
@@ -96,3 +101,154 @@ def test_train_step_updates_running_stats_and_grads_flow():
     mu = m["bn_stats"][0][0]
     np.testing.assert_allclose(np.asarray(p2["layers"][0]["bn_mean"]),
                                np.asarray(0.5 * mu), rtol=1e-6)
+
+
+def _patches(x, k=3):
+    """The windows through XLA's patch convolution (what the CNN path ran
+    before the slice-built :func:`im2col`): channel-major, transposed."""
+    b, h, w, c = x.shape
+    p = jax.lax.conv_general_dilated_patches(
+        x, (k, k), (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST)
+    return jnp.swapaxes(p.reshape(b, h, w, c, k * k), -1, -2).reshape(
+        b, h, w, k * k * c)
+
+
+@pytest.mark.parametrize("shape", [(2, 5, 7, 3), (1, 1, 4, 2), (2, 6, 3, 8)])
+def test_im2col_equals_patch_convolution_and_its_vjp(shape):
+    """Same windows and the same transpose (the STE's dx) as the patch
+    convolution, odd and one-pixel sizes included."""
+    x = jax.random.normal(jax.random.PRNGKey(3), shape)
+    np.testing.assert_array_equal(np.asarray(im2col(x)),
+                                  np.asarray(_patches(x)))
+    g = jax.random.normal(jax.random.PRNGKey(4), shape[:3] + (9 * shape[3],))
+    got = jax.vjp(im2col, x)[1](g)[0]
+    want = jax.vjp(_patches, x)[1](g)[0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("hw", [(5, 7), (1, 1), (2, 3), (32, 32)])
+def test_multiplicity_counts_the_windows_of_each_pixel(hw):
+    """The transpose of the window extraction applied to ones counts, per
+    pixel, the windows that hold it: 9 inside, 6 on an edge, 4 in a
+    corner (fewer on a one- or two-pixel side)."""
+    x = jnp.zeros((1, *hw, 2))
+    ones = jnp.ones(hw + (18,))[None]
+    counted = jax.vjp(im2col, x)[1](ones)[0]
+    np.testing.assert_array_equal(
+        np.asarray(counted),
+        np.broadcast_to(multiplicity(*hw)[None, :, :, None], x.shape))
+
+
+@pytest.mark.parametrize("shape", [(2, 5, 7, 3), (1, 1, 4, 2), (2, 6, 3, 8)])
+@pytest.mark.parametrize("bx,coding", [(1, "xnor"), (4, "xnor"), (4, "and")])
+def test_windows_on_the_grid_are_the_quantized_windows(shape, bx, coding):
+    """Quantize per pixel, then window on the int8 grid: the grid of the
+    float windows bit for bit, the padded border included (+1 under 1-b
+    XNOR, 0 otherwise); the scale exact from max|x|.  The 1-b mean is
+    summed in another order: it is held to the float64 mean of the
+    windows, within four float32 epsilons (the float windows' own float32
+    mean is no yardstick here: XLA's CPU reduction of it lies 9 ulp off
+    at the smallest shape)."""
+    spec = accel.ExecSpec(backend="pallas", ba=1, bx=bx, coding=coding)
+    x = jax.random.normal(jax.random.PRNGKey(5), shape)
+    got = quantize_windows(x, spec, 3)
+    want = quantize_input(im2col(x), spec)
+    assert got.q.dtype == jnp.int8 and got.q.shape == want.q.shape
+    np.testing.assert_array_equal(np.asarray(got.q), np.asarray(want.q))
+    pad = 1 if (bx, coding) == (1, "xnor") else 0
+    assert int(got.q[0, 0, 0, 0]) == pad          # the corner's (-1, -1)
+    if bx == 1:
+        exact = np.abs(np.asarray(im2col(x), np.float64)).mean()
+        np.testing.assert_allclose(float(got.scale), exact,
+                                   rtol=4 * np.finfo(np.float32).eps)
+    else:
+        assert float(got.scale) == float(want.scale)
+
+
+def _netb(**spec_kw):
+    net = NETWORK_B.reduced()
+    spec = accel.ExecSpec(backend="pallas", ba=1, bx=1, **spec_kw)
+    return dataclasses.replace(net,
+                               policy=accel.PrecisionPolicy.uniform(spec))
+
+
+def _conv_records(recs, net):
+    n_conv = sum(layer.kind == "conv" for layer in net.layers)
+    assert len(recs) == len(net.layers)
+    return recs[:n_conv], recs[n_conv:]
+
+
+def test_pallas_windows_on_the_grid_match_digital_int():
+    """Network B (reduced) on ``pallas``: all six conv MVMs take their
+    windows on the grid and the logits are ``digital_int``'s (the ideal,
+    float windows); the fc layers bypass."""
+    net = _netb()
+    params = init_cnn(KEY, net)
+    imgs = jax.random.normal(jax.random.PRNGKey(6), (2, 32, 32, 3))
+    with accel.trace() as recs:
+        got = jax.jit(lambda p, x: cnn_forward(p, x, net))(params, imgs)
+    conv, fc = _conv_records(recs, net)
+    assert [r.grid_windows for r in conv] == [1] * 6
+    assert [r.grid_windows for r in fc] == [0] * 3
+    want = cnn_forward(params, imgs, net, backend="digital_int")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["x_per_row", "bpbs", "train"])
+def test_float_windows_where_the_grid_cannot_take_them(case):
+    """A per-row input scale, another backend, or training: the windows
+    are formed on the float activations, so no conv MVM counts."""
+    net = _netb(x_per_row=case == "x_per_row")
+    params = init_cnn(KEY, net)
+    imgs = jax.random.normal(jax.random.PRNGKey(7), (2, 32, 32, 3))
+    with accel.trace() as recs:
+        if case == "train":
+            cnn_forward(params, imgs, net, train=True)
+        else:
+            cnn_forward(params, imgs, net,
+                        backend="bpbs" if case == "bpbs" else None)
+    conv, _ = _conv_records(recs, net)
+    assert len(conv) == 6 and all(r.grid_windows == 0 for r in conv)
+
+
+def test_conv_dispatch_records_the_same_mvm():
+    """The energy trace sees the conv as the MVM of its windows — the
+    same rows, N, M, measured sparsity and plane skips — whether the
+    windows were formed on the grid or handed in."""
+    spec = accel.ExecSpec(backend="pallas", ba=1, bx=1)
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 6, 6, 4))
+    w = jax.random.normal(jax.random.PRNGKey(9), (36, 16))
+    with accel.trace() as on_grid:
+        y = accel.matmul(x, w, spec, window=3)
+    with accel.trace() as handed:
+        y0 = accel.matmul(im2col(x), w, spec)
+    assert on_grid[0].grid_windows == 1 and on_grid[0].sparsity is not None
+    assert [dataclasses.replace(r, grid_windows=0) for r in on_grid] \
+        == list(handed)
+    assert accel.energy_summary(on_grid) == accel.energy_summary(handed)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y0), rtol=1e-6)
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_conv_dispatch_has_the_ste_gradients(fused):
+    """Gradients through ``window=3`` are those of the float windows'
+    GEMM (STE), with and without a fused epilogue."""
+    spec = accel.ExecSpec(backend="pallas", ba=1, bx=1)
+    x = jax.random.normal(jax.random.PRNGKey(10), (2, 5, 5, 4))
+    w = jax.random.normal(jax.random.PRNGKey(11), (36, 8))
+    post = (accel.Postreduce(scale=jnp.full((8,), 0.5), bias=None,
+                             act="relu", saturate=True) if fused else None)
+
+    def loss(x, w, window):
+        h = x if window else im2col(x)
+        y = accel.matmul(h, w, spec, post=post, window=window)
+        return jnp.sum(y * jnp.arange(y.size).reshape(y.shape) / y.size)
+
+    got = jax.grad(loss, argnums=(0, 1))(x, w, 3)
+    want = jax.grad(loss, argnums=(0, 1))(x, w, None)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)

@@ -75,7 +75,7 @@ def test_compiled_cnn_step_carries_every_scope(backend, layers, want):
 @pytest.mark.parametrize("op_name,expected", [
     ("jit(f)/cnn.layer3/jit(cima_mvm_planes)/cima.pad/jit(_pad)/pad",
      ("cnn.layer3", "cima.pad")),
-    ("jit(f)/cnn.layer0/cnn.im2col/conv_general_dilated",
+    ("jit(f)/cnn.layer0/cnn.im2col/concatenate",
      ("cnn.layer0", "cnn.im2col")),
     ("jit(f)/cnn.layer2/cima.quantize_x/cima.post/mul",      # innermost
      ("cnn.layer2", "cima.post")),
