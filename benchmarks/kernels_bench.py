@@ -10,8 +10,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.bpbs import BpbsConfig
-from repro.kernels import ops
+from repro.core.bpbs import BpbsConfig, weight_planes
+from repro.kernels.cima_mvm import cima_mvm
+from repro.kernels.flash_attention import flash_attention
 
 from .common import emit, time_call
 
@@ -33,8 +34,9 @@ def run():
         x = jnp.asarray(2 * rng.integers(-4, 5, (bb, n)), jnp.float32)
         w = jnp.asarray(2 * rng.integers(-4, 5, (n, m)), jnp.float32)
         cfg = BpbsConfig(ba=ba, bx=bx)
-        us = time_call(lambda x=x, w=w, cfg=cfg, bb=bb, bm=bm: ops.cima_mvm(
-            x, w, cfg, block_b=bb, block_m=bm), iters=3, warmup=1)
+        ws = weight_planes(w, cfg).astype(jnp.int8)   # stationary weights
+        us = time_call(lambda x=x, ws=ws, cfg=cfg, bb=bb, bm=bm: cima_mvm(
+            x, ws, cfg, block_b=bb, block_m=bm), iters=3, warmup=1)
         flops = 2.0 * bb * n * m * ba * bx
         vmem = cima_vmem_bytes(cfg.bank_n, bb, bm, bx, ba)
         hbm = bb * bx * n + n * ba * m + bb * m * 4
@@ -48,8 +50,8 @@ def run():
     q = jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.bfloat16)
     k = jnp.asarray(rng.normal(size=(b, 1, s, d)), jnp.bfloat16)
     v = jnp.asarray(rng.normal(size=(b, 1, s, d)), jnp.bfloat16)
-    us = time_call(lambda: ops.flash_attention(q, k, v, block_q=128,
-                                               block_k=128),
+    us = time_call(lambda: flash_attention(q, k, v, block_q=128,
+                                           block_k=128),
                    iters=3, warmup=1)
     bq = bk = 128
     vmem = (bq * d + 2 * bk * d) * 2 + bq * d * 4 + bq * (4 + 4)

@@ -1,21 +1,30 @@
 """Built-in execution backends.
 
-Every quantizing backend shares one operand-quantization discipline
-(:func:`quantize_input` / :func:`weight_grid` / :func:`rescale`), so
+``digital`` is the plain float GEMM.  Every quantizing backend is one row
+of :data:`INT_CORES`: the weight operand its integer MVM reads — the
+grid ``[N, M]`` or the bit planes ``[N, B_A, M]`` — and the MVM itself.
+One scaffold (:func:`run_core`) runs every row: the input grid
+(:func:`quantize_input`, or :func:`quantize_windows` for a conv layer's
+pixels), the weight operand (:func:`weight_operand`), the core, then
+:func:`rescale` and :func:`apply_post` — or, for the core that fuses the
+epilogue (the Pallas kernel), the epilogue handed to the core.  So
 ``digital_int`` is the bit-true reference for ``bpbs``/``bpbs_ref``/
 ``pallas`` by construction: they consume identical integer grids and
-differ only in how the integer MVM itself is evaluated.
+differ only in how the integer MVM itself is evaluated.  The mesh path
+(:mod:`repro.accel.shard`) runs the same cores on its per-device tiles.
 
 Weight-stationary serving: when ``ctx.image`` carries a compiled
 :class:`~repro.accel.program.CimaImage`, the weight side comes from the
-stored bit planes (a transpose/recombination of exact small integers —
-bit-identical to quantizing on the fly) and **zero** per-call
-``quantize``/``weight_planes`` ops run.  The input side is dynamic and
-still quantizes per call, exactly as the chip streams activations.
+stored grid or bit planes (exact small integers — bit-identical to
+quantizing on the fly) and **zero** per-call ``quantize``/
+``weight_planes`` ops run.  The input side is dynamic and still
+quantizes per call, exactly as the chip streams activations.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -86,53 +95,25 @@ def quantize_windows(x: jax.Array, spec: ExecSpec, k: int) -> QTensor:
     return QTensor(cs(im2col(q, k, zero), ("dp",)), scale, bits, coding)
 
 
-def weight_grid(w: jax.Array, spec: ExecSpec,
-                ctx: ExecContext) -> QTensor:
-    """The weight operand on the spec's integer grid.
+def weight_operand(w: jax.Array, spec: ExecSpec, ctx: ExecContext,
+                   core: IntCore) -> tuple[jax.Array, jax.Array]:
+    """``(operand, scale)``: the weight side ``core`` reads, in its dtype.
 
-    Program path: the image's stored int16 grid casts straight to f32
-    (exact small integers; zero quantize ops).  Fallback: quantize per
-    call.
+    Program path: the image's stored grid or planes, cast where the core
+    reads another dtype (exact small integers; zero quantize ops).
+    Fallback: quantize per call, then, for the planes, decompose
+    (:func:`repro.core.bpbs.weight_planes`).
     """
     img = ctx.image
     with jax.named_scope("cima.quantize_w"):
         if img is not None:
-            return QTensor(img.wq.astype(jnp.float32), img.scale,
-                           spec.ba, spec.coding)
-        return quantize(w, spec.ba, spec.coding,
-                        axis=1 if spec.per_channel else None)
-
-
-def weight_planes_for(w: jax.Array, spec: ExecSpec,
-                      ctx: ExecContext) -> tuple[jax.Array, jax.Array]:
-    """``(ws [N, B_A, M], scale)`` for the plane-consuming backends.
-
-    Program path: the image's planes in the kernel layout, widened to
-    f32 in one pass.  (Measured on CPU XLA, one upfront int8->f32 cast
-    beats feeding int8 straight into the per-bank bf16 GEMMs by ~1.6x —
-    the element-wise widening fuses poorly inside the bank loop.  The
-    ``pallas`` backend is the true 1-byte-per-plane-element streaming
-    path: it consumes the stored int8 image directly and casts in-tile.)
-    Fallback: quantize + decompose + transpose per call.
-    """
-    img = ctx.image
-    with jax.named_scope("cima.quantize_w"):
-        if img is not None:
-            return img.ws.astype(jnp.float32), img.scale
+            return getattr(img, core.reads).astype(core.dtype), img.scale
         qw = quantize(w, spec.ba, spec.coding,
                       axis=1 if spec.per_channel else None)
-        return jnp.transpose(weight_planes(qw.q, spec.bpbs()),
-                             (0, 2, 1)), qw.scale
-
-
-def quantize_operands(x: jax.Array, w: jax.Array,
-                      spec: ExecSpec) -> tuple[QTensor, QTensor]:
-    """Quantize both operands onto the spec's coding grids (the on-the-fly
-    path; kept for external callers)."""
-    qx = quantize_input(x, spec)
-    qw = quantize(w, spec.ba, spec.coding,
-                  axis=1 if spec.per_channel else None)
-    return qx, qw
+    if core.reads == "wq":
+        return qw.q, qw.scale
+    with jax.named_scope("cima.planes"):
+        return weight_planes(qw.q, spec.bpbs()).astype(core.dtype), qw.scale
 
 
 def rescale(y_int: jax.Array, x_scale: jax.Array, w_scale: jax.Array,
@@ -162,41 +143,55 @@ def digital(x: jax.Array, w: jax.Array, spec: ExecSpec,
     return apply_post(jnp.einsum("...n,nm->...m", x, w), ctx.post, spec)
 
 
-@register_backend("digital_int")
-def digital_int(x: jax.Array, w: jax.Array, spec: ExecSpec,
-                ctx: ExecContext) -> jax.Array:
+def _int_gemm(x_q, wq, spec, key):
     """Bit-true integer compute at (B_A, B_X) — the Fig. 11 "ideal"."""
-    qx = quantize_input(x, spec)
-    qw = weight_grid(w, spec, ctx)
-    y_int = jnp.einsum("...n,nm->...m", qx.q.astype(jnp.float32),
-                       qw.q.astype(jnp.float32))
-    return apply_post(rescale(y_int, qx.scale, qw.scale, spec),
-                      ctx.post, spec)
+    return jnp.einsum("...n,nm->...m", x_q.astype(jnp.float32),
+                      wq.astype(jnp.float32))
 
 
-@register_backend("bpbs")
-def bpbs(x: jax.Array, w: jax.Array, spec: ExecSpec,
-         ctx: ExecContext) -> jax.Array:
-    """Mixed-signal BP/BS pipeline, fast GEMM-identity path.  The fused
-    ``ctx.post`` epilogue applies right after plane recombination, inside
-    the same jitted op — XLA fuses it with the barrel-shift einsum, no
-    HBM round-trip between reduce and post-ops."""
-    qx = quantize_input(x, spec)
-    ws, w_scale = weight_planes_for(w, spec, ctx)
-    y_int = bpbs_matmul_planes(qx.q, ws, spec.bpbs(), ctx.key)
-    return apply_post(rescale(y_int, qx.scale, w_scale, spec),
-                      ctx.post, spec)
+def _bpbs(x_q, ws, spec, key):
+    """Mixed-signal BP/BS pipeline, fast GEMM-identity path; the epilogue
+    applies right after plane recombination, inside the same jitted op —
+    XLA fuses it with the barrel-shift einsum."""
+    return bpbs_matmul_planes(x_q, ws, spec.bpbs(), key)
 
 
-@register_backend("bpbs_ref")
-def bpbs_ref(x: jax.Array, w: jax.Array, spec: ExecSpec,
-             ctx: ExecContext) -> jax.Array:
+def _bpbs_ref(x_q, ws, spec, key):
     """Cell-by-cell charge-share physics (slow; validation only)."""
-    qx = quantize_input(x, spec)
-    ws, w_scale = weight_planes_for(w, spec, ctx)
-    y_int = bpbs_matmul_planes_reference(qx.q, ws, spec.bpbs())
-    return apply_post(rescale(y_int, qx.scale, w_scale, spec),
-                      ctx.post, spec)
+    return bpbs_matmul_planes_reference(x_q, ws, spec.bpbs())
+
+
+def _pallas(x_q, ws, spec, key, **fused):
+    """The Pallas TPU kernel (interpret mode off the TPU unless the spec
+    says otherwise); ``fused`` arms its datapath epilogue."""
+    from repro.kernels.cima_mvm import cima_mvm
+
+    return cima_mvm(x_q, ws, spec.bpbs(), interpret=spec.interpret, **fused)
+
+
+@dataclasses.dataclass(frozen=True)
+class IntCore:
+    """One quantizing backend: its integer MVM and the weight operand it
+    reads."""
+
+    # the CimaImage field the core reads: "wq", the grid [N, M], or "ws",
+    # the bit planes [N, B_A, M]
+    reads: str
+    # the operand's dtype on one device: per-call operands are made in
+    # it and an image's is cast to it (the mesh path passes the image's)
+    dtype: Any
+    # (x_q, w, spec, key) -> y_int [..., M]; with ``fuses``, also takes
+    # the kernel's epilogue (escale, pbias, act, by_bits) by keyword
+    mvm: Callable
+    fuses: bool = False
+
+
+INT_CORES = {
+    "digital_int": IntCore("wq", jnp.float32, _int_gemm),
+    "bpbs": IntCore("ws", jnp.float32, _bpbs),
+    "bpbs_ref": IntCore("ws", jnp.float32, _bpbs_ref),
+    "pallas": IntCore("ws", jnp.int8, _pallas, fuses=True),
+}
 
 
 def _kernel_fusable(post, m: int) -> bool:
@@ -211,49 +206,30 @@ def _kernel_fusable(post, m: int) -> bool:
     return per_col(post.scale) and per_col(post.bias)
 
 
-@register_backend("pallas")
-def pallas(x: jax.Array, w: jax.Array, spec: ExecSpec,
-           ctx: ExecContext) -> jax.Array:
-    """The Pallas TPU kernel (interpret mode on CPU unless overridden).
-    A per-column ``ctx.post`` fuses into the kernel's datapath epilogue:
-    the quantization rescale folds into the scale registers and the
-    output leaves the kernel already post-reduced.  With ``ctx.window``
-    set, ``x`` is a layer's pixels and the windows are formed on the int8
-    grid (:func:`quantize_windows`)."""
-    from repro.kernels import ops as kernel_ops
-
+def run_core(core: IntCore, x: jax.Array, w: jax.Array, spec: ExecSpec,
+             ctx: ExecContext) -> jax.Array:
+    """One quantizing backend: quantize -> weight operand -> integer MVM
+    -> rescale -> epilogue.  With ``ctx.window`` set, ``x`` is a layer's
+    pixels and the windows are formed on the int8 grid
+    (:func:`quantize_windows`).  A per-column ``ctx.post`` fuses into a
+    ``fuses`` core: the quantization rescale folds into the scale
+    registers and the output leaves the core already post-reduced."""
     qx = (quantize_windows(x, spec, ctx.window) if ctx.window
           else quantize_input(x, spec))
-    img = ctx.image
-    if img is not None:
-        ws_planes, w_scale = img.ws, img.scale
-    else:
-        with jax.named_scope("cima.quantize_w"):
-            qw = quantize(w, spec.ba, spec.coding,
-                          axis=1 if spec.per_channel else None)
-        ws_planes, w_scale = None, qw.scale
-
+    wop, w_scale = weight_operand(w, spec, ctx, core)
     post = ctx.post
-    m = int(w.shape[-1])
-    if post is not None and _kernel_fusable(post, m):
+    if core.fuses and post is not None \
+            and _kernel_fusable(post, int(w.shape[-1])):
         sw = w_scale.reshape(-1) if spec.per_channel else w_scale
         escale = qx.scale * sw
         if post.scale is not None:
             escale = escale * post.scale
-        fused = dict(escale=escale, pbias=post.bias, act=post.act,
-                     by_bits=post.resolve_bits(spec.bx, spec.ba))
-        if img is not None:
-            return kernel_ops.cima_mvm_from_planes(
-                qx.q, ws_planes, spec.bpbs(), interpret=spec.interpret,
-                **fused)
-        return kernel_ops.cima_mvm(qx.q, qw.q, spec.bpbs(),
-                                   interpret=spec.interpret, **fused)
-
-    if img is not None:
-        # the image already stores the kernel's [N, BA, M] int8 layout
-        y_int = kernel_ops.cima_mvm_from_planes(qx.q, ws_planes, spec.bpbs(),
-                                                interpret=spec.interpret)
-    else:
-        y_int = kernel_ops.cima_mvm(qx.q, qw.q, spec.bpbs(),
-                                    interpret=spec.interpret)
+        return core.mvm(qx.q, wop, spec, ctx.key, escale=escale,
+                        pbias=post.bias, act=post.act,
+                        by_bits=post.resolve_bits(spec.bx, spec.ba))
+    y_int = core.mvm(qx.q, wop, spec, ctx.key)
     return apply_post(rescale(y_int, qx.scale, w_scale, spec), post, spec)
+
+
+for _name, _core in INT_CORES.items():
+    register_backend(_name, functools.partial(run_core, _core))

@@ -47,10 +47,12 @@ import jax.numpy as jnp
 
 from repro.core.quant import Coding, quantize
 
+from .backends import INT_CORES
+
 # Backends whose weight-side numerics are the shared integer grid of
 # repro.core.quant — a compiled image is valid for ANY of them (that is
 # what lets override(backend=...) flip substrates without recompiling).
-PROGRAM_BACKENDS = ("digital_int", "bpbs", "bpbs_ref", "pallas")
+PROGRAM_BACKENDS = tuple(INT_CORES)
 
 
 # ---------------------------------------------------------------- images
@@ -214,7 +216,7 @@ def _compile_matrices(w: jax.Array, ba: int, coding: Coding,
     ``(ws [C, N, BA, M] int8, wq [C, N, M] int16, scale)``.
 
     One jitted program per shape, mapped over the stack with
-    ``lax.map``: the f32 plane intermediates (``[N, M, BA]``) exist for
+    ``lax.map``: the f32 plane intermediates (``[N, BA, M]``) exist for
     one matrix at a time, never for the whole stack — a full-width
     model's images build next to its params on one device.
     """
@@ -225,8 +227,7 @@ def _compile_matrices(w: jax.Array, ba: int, coding: Coding,
     def one(wi):
         qw = quantize(wi.astype(jnp.float32), ba, coding,
                       axis=1 if per_channel else None)
-        wp = weight_planes(qw.q, cfg)                     # [N, M, BA]
-        return (jnp.transpose(wp, (0, 2, 1)).astype(jnp.int8),
+        return (weight_planes(qw.q, cfg).astype(jnp.int8),
                 qw.q.astype(jnp.int16), qw.scale)
 
     return jax.lax.map(one, w)

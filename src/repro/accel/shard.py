@@ -37,9 +37,12 @@ biases carrying the residual) split their leading dim with the batch.
 Because quantization is global and the grid is fixed before the split,
 the 2D path is bit-for-bit identical to the 1D and unsharded paths.
 
-The Pallas ``cima_mvm`` kernel composes directly: inside the body it sees
-the local ``[N_loc, BA, M_loc]`` planes, so its bank grid dimension *is*
-the per-device tile.
+The body runs the backend's integer core from
+:data:`~repro.accel.backends.INT_CORES`, the same one the single-device
+path runs, on the image field that core reads.  The Pallas kernel
+composes directly: inside the body it sees the local
+``[N_loc, BA, M_loc]`` planes, so its bank grid dimension *is* the
+per-device tile.
 
 Trace semantics (no per-shard double-counting): the dispatcher records
 ONE logical :class:`~repro.accel.context.MvmRecord` per matmul — with the
@@ -92,15 +95,11 @@ def _out_spec(ndim: int, partition: str, lead: Optional[str] = None) -> P:
     return P(*spec)
 
 
-def _ws_spec(partition: str) -> P:
-    # ws layout [N, BA, M] — always data-replicated
-    return P("model", None, None) if partition == "row" \
-        else P(None, None, "model")
-
-
-def _wq_spec(partition: str) -> P:
-    # wq layout [N, M] — always data-replicated
-    return P("model", None) if partition == "row" else P(None, "model")
+def _w_spec(partition: str, ndim: int) -> P:
+    # the grid [N, M] or the planes [N, BA, M] — always data-replicated
+    spec = [None] * ndim
+    spec[0 if partition == "row" else -1] = "model"
+    return P(*spec)
 
 
 def _post_spec(arr, part: str, m: int, lead: Optional[str] = None,
@@ -139,7 +138,7 @@ def sharded_program_matmul(x: jax.Array, spec, image, mesh,
     """
     from repro.distributed.autoshard import manual
 
-    from .backends import quantize_input, rescale
+    from .backends import INT_CORES, quantize_input, rescale
 
     part = image.partition
     assert part in ("col", "row"), part
@@ -151,59 +150,33 @@ def sharded_program_matmul(x: jax.Array, spec, image, mesh,
     rows = int(qx.q.shape[0]) if qx.q.ndim >= 2 else 0
 
     # one scaffold (psum placement, manual() scoping, in/out specs) for
-    # every backend — only the local tile compute differs
-    if spec.backend == "digital_int":
-        operands = (image.wq,)
-        w_specs = (_wq_spec(part),)
-
-        def local(xq, wq):
-            return jnp.einsum("...n,nm->...m", xq.astype(jnp.float32),
-                              wq.astype(jnp.float32))
-
-    elif spec.backend in ("bpbs", "bpbs_ref"):
-        from repro.core.bpbs import (bpbs_matmul_planes,
-                                     bpbs_matmul_planes_reference)
-
-        bcfg = spec.bpbs()
-        has_key = spec.backend == "bpbs" and key is not None
-        operands = (image.ws,) + ((key,) if has_key else ())
-        w_specs = (_ws_spec(part),) + ((P(),) if has_key else ())
-
-        def local(xq, ws, *k):
-            # local banks AND local ADC epilogue: each chip digitizes its
-            # own column sums before the digital partial-sum all-reduce.
-            # Each chip has its own ADCs: fold the device index into the
-            # noise key so shards draw INDEPENDENT noise fields (a
-            # replicated key would correlate the chips bit-for-bit).
-            kd = None
-            if k:
-                kd = jax.random.fold_in(k[0],
-                                        jax.lax.axis_index("model"))
-                if lead is not None:
-                    # data shards are distinct chips too: decorrelate
-                    # their ADC noise fields exactly like model shards
-                    kd = jax.random.fold_in(kd, jax.lax.axis_index(lead))
-            if spec.backend == "bpbs":
-                return bpbs_matmul_planes(xq, ws, bcfg, kd)
-            return bpbs_matmul_planes_reference(xq, ws, bcfg)
-
-    elif spec.backend == "pallas":
-        from repro.kernels import ops as kernel_ops
-
-        bcfg = spec.bpbs()
-        operands = (image.ws,)
-        w_specs = (_ws_spec(part),)
-
-        def local(xq, ws):
-            # the kernel's bank grid dimension is the per-device tile
-            return kernel_ops.cima_mvm_from_planes(
-                xq, ws, bcfg, interpret=spec.interpret)
-
-    else:
+    # every backend — only the local tile compute differs: the backend's
+    # integer core on the image field it reads
+    core = INT_CORES.get(spec.backend)
+    if core is None:
         raise ValueError(
             f"backend {spec.backend!r} has no shard_map execution path; "
-            "mesh-partitioned images support "
-            "digital_int / bpbs / bpbs_ref / pallas")
+            f"mesh-partitioned images support {', '.join(INT_CORES)}")
+    w_op = getattr(image, core.reads)
+    operands = (w_op,) + ((key,) if key is not None else ())
+    w_specs = ((_w_spec(part, w_op.ndim),)
+               + ((P(),) if key is not None else ()))
+
+    def local(xq, w, *k):
+        # local banks AND local ADC epilogue: each chip digitizes its
+        # own column sums before the digital partial-sum all-reduce.
+        # Each chip has its own ADCs: fold the device index into the
+        # noise key so shards draw INDEPENDENT noise fields (a
+        # replicated key would correlate the chips bit-for-bit).  The
+        # kernel's bank grid dimension is the per-device tile.
+        kd = None
+        if k:
+            kd = jax.random.fold_in(k[0], jax.lax.axis_index("model"))
+            if lead is not None:
+                # data shards are distinct chips too: decorrelate
+                # their ADC noise fields exactly like model shards
+                kd = jax.random.fold_in(kd, jax.lax.axis_index(lead))
+        return core.mvm(xq, w, spec, kd)
 
     m = image.m
     n_local = len(operands)

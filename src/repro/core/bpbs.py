@@ -66,8 +66,12 @@ class BpbsConfig:
 
 
 def weight_planes(w_q: jax.Array, cfg: BpbsConfig) -> jax.Array:
-    """Matrix-element bit planes, shape [N, M, B_A] (column-parallel layout)."""
-    return int_to_planes(w_q, cfg.ba, cfg.coding)
+    """Matrix-element bit planes ``[N, B_A, M]`` (float32, exact small
+    integers): the layout the Pallas kernel reads and a compiled
+    :class:`~repro.accel.program.CimaImage` stores, as the chip lays a
+    row's B_A bit-columns side by side.  Callers cast (int8 for the image
+    and the kernel)."""
+    return jnp.transpose(int_to_planes(w_q, cfg.ba, cfg.coding), (0, 2, 1))
 
 
 def input_plane_list(x_q: jax.Array, cfg: BpbsConfig) -> tuple[list, jax.Array]:
@@ -242,8 +246,7 @@ def bpbs_matmul_int(
 
     On-the-fly wrapper: decomposes ``w_q`` per call, then runs the
     plane-level path (:func:`bpbs_matmul_planes`)."""
-    ws = jnp.transpose(weight_planes(w_q, cfg), (0, 2, 1))
-    return bpbs_matmul_planes(x_q, ws, cfg, key)
+    return bpbs_matmul_planes(x_q, weight_planes(w_q, cfg), cfg, key)
 
 
 def bpbs_matmul_planes_reference(
@@ -282,5 +285,4 @@ def bpbs_matmul_int_reference(
     x_q: jax.Array, w_q: jax.Array, cfg: BpbsConfig
 ) -> jax.Array:
     """On-the-fly physics reference: decompose ``w_q``, then the cell model."""
-    ws = jnp.transpose(weight_planes(w_q, cfg), (0, 2, 1))
-    return bpbs_matmul_planes_reference(x_q, ws, cfg)
+    return bpbs_matmul_planes_reference(x_q, weight_planes(w_q, cfg), cfg)

@@ -193,32 +193,36 @@ def bank_width(n: int, cfg: BpbsConfig) -> int:
     return min(cfg.bank_n, -(-n // LANES) * LANES)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("cfg", "block_b", "block_m", "interpret", "act",
-                     "by_bits"),
-)
-def cima_mvm_planes(
-    x: jax.Array,           # [B, N] integer input grid (prepare_inputs)
-    ws: jax.Array,          # [N, BA, M] int8 weight planes
-    fs: jax.Array,          # [n_banks] f32 ADC full scale per bank
+def _grid_dtype(cfg: BpbsConfig):
+    """The narrowest integer dtype that holds the input coding grid."""
+    lo, hi = int_range(cfg.bx, cfg.coding)
+    return jnp.int8 if -128 <= lo and hi <= 127 else jnp.int32
+
+
+def cima_mvm(
+    x_q: jax.Array,                       # [..., N] integer input grid
+    ws: jax.Array,                        # [N, BA, M] int8 weight planes
     cfg: BpbsConfig,
     block_b: int = 128,
     block_m: int = 256,
-    interpret: bool = True,
-    escale: Optional[jax.Array] = None,   # [M]|[B,M]|scalar: rescale*scale
+    interpret: Optional[bool] = None,
+    escale: Optional[jax.Array] = None,   # [M]|[...,M]|scalar
     pbias: Optional[jax.Array] = None,    # [M]|scalar: datapath bias regs
     act: Optional[str] = None,
     by_bits: Optional[int] = None,
 ) -> jax.Array:
-    """Raw kernel entry on the input grid and the weight planes.  Returns
-    [B, M] f32.
+    """The BP/BS MVM kernel: the quantized inputs ``x_q`` times the weight
+    bit planes ``ws`` (:func:`repro.core.bpbs.weight_planes`, the layout a
+    :class:`~repro.accel.program.CimaImage` stores) -> [..., M] f32.
 
-    The input bit planes, each bank's unmasked-row count and the
-    plane-skip liveness are formed in-kernel from the grid's VMEM tile,
-    once per (row tile, bank).
-
-    ``block_m`` shrinks to M rounded up to 128 lanes where M is narrower.
+    The input grid reaches the kernel as one ``[rows, N]`` operand in the
+    narrowest integer dtype that holds it; the w2b Reshaping Buffer and
+    Sparsity Controller roles (bit planes, per-bank unmasked counts,
+    plane liveness) run in-kernel on its VMEM tile, once per (row tile,
+    bank).  The ADC full scale of each bank is its row count, derived
+    from N.  ``block_m`` shrinks to M rounded up to 128 lanes where M is
+    narrower.  ``interpret=None`` compiles the kernel on a TPU and
+    interprets it elsewhere.
 
     ``escale``/``pbias``/``act``/``by_bits`` arm the fused near-memory
     datapath epilogue (paper Fig. 8): after the last bank accumulates,
@@ -226,9 +230,41 @@ def cima_mvm_planes(
     saturation in-VMEM — the output leaves the kernel already
     post-reduced.  ``escale`` combines the quantization rescale
     (``x_scale * w_scale``) with the datapath scale registers; without
-    the epilogue the kernel returns the recombined integer-grid output
-    as before.
+    the epilogue the kernel returns the recombined integer-grid output.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    lead, n = x_q.shape[:-1], ws.shape[0]
+    n_banks = -(-n // cfg.bank_n)
+    rows = np.minimum(cfg.bank_n, n - np.arange(n_banks) * cfg.bank_n)
+    with jax.named_scope("cima.planes"):
+        x2 = x_q.reshape(-1, x_q.shape[-1]).astype(_grid_dtype(cfg))
+        fs = jnp.asarray(rows, dtype=jnp.float32)
+    y = _cima_mvm_planes(x2, ws, fs, cfg, block_b, block_m, interpret,
+                         escale, pbias, act, by_bits)
+    with jax.named_scope("cima.post"):
+        return y.reshape(*lead, ws.shape[2])
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("cfg", "block_b", "block_m", "interpret", "act",
+                     "by_bits"),
+)
+def _cima_mvm_planes(
+    x: jax.Array,           # [B, N] integer input grid
+    ws: jax.Array,          # [N, BA, M] int8 weight planes
+    fs: jax.Array,          # [n_banks] f32 ADC full scale per bank
+    cfg: BpbsConfig,
+    block_b: int,
+    block_m: int,
+    interpret: bool,
+    escale: Optional[jax.Array],
+    pbias: Optional[jax.Array],
+    act: Optional[str],
+    by_bits: Optional[int],
+) -> jax.Array:
+    """The ``pallas_call`` on 2D operands, padded to its blocks: [B, M]."""
     b, n = x.shape
     n_w, ba, m = ws.shape
     assert n_w == n and ba == cfg.ba
@@ -332,92 +368,3 @@ def cima_mvm_planes(
         out = kernel(*scalars, *operands)
     with jax.named_scope("cima.post"):
         return out[:b, :m]
-
-
-def grid_dtype(cfg: BpbsConfig):
-    """The narrowest integer dtype that holds the input coding grid."""
-    lo, hi = int_range(cfg.bx, cfg.coding)
-    return jnp.int8 if -128 <= lo and hi <= 127 else jnp.int32
-
-
-def prepare_inputs(x_q: jax.Array, cfg: BpbsConfig):
-    """The kernel's input operand: the quantized grid as one 2D
-    ``[rows, N]`` array in :func:`grid_dtype`.  The w2b Reshaping Buffer
-    and Sparsity Controller roles (bit planes, per-bank unmasked counts,
-    plane liveness) run inside the kernel, next to the array."""
-    lead = x_q.shape[:-1]
-    with jax.named_scope("cima.planes"):
-        x2 = x_q.reshape(-1, x_q.shape[-1]).astype(grid_dtype(cfg))
-    return x2, lead
-
-
-def bank_full_scales(n: int, cfg: BpbsConfig) -> jax.Array:
-    """Static ADC full scale per bank: the bank's (possibly ragged last)
-    row count.  Derivable from N alone, so a stored weight image never
-    needs to carry it."""
-    n_banks = -(-n // cfg.bank_n)
-    sizes = np.minimum(
-        np.full(n_banks, cfg.bank_n), n - np.arange(n_banks) * cfg.bank_n
-    )
-    with jax.named_scope("cima.planes"):
-        return jnp.asarray(sizes, dtype=jnp.float32)
-
-
-def prepare_weights(w_q: jax.Array, cfg: BpbsConfig):
-    """Weight bit planes [N, BA, M] (precomputable: weights are stationary
-    in the CIMA — reloading costs ~18k cycles on-chip, paper Fig. 8).
-    This is exactly the layout a :class:`~repro.accel.program.CimaImage`
-    stores once at program-load time."""
-    from repro.core.bpbs import weight_planes
-
-    with jax.named_scope("cima.planes"):
-        wp = weight_planes(w_q, cfg)               # [N, M, BA]
-        ws = jnp.transpose(wp, (0, 2, 1)).astype(jnp.int8)
-    return ws, bank_full_scales(w_q.shape[0], cfg)
-
-
-def cima_mvm(
-    x_q: jax.Array,
-    w_q: jax.Array,
-    cfg: BpbsConfig,
-    block_b: int = 128,
-    block_m: int = 256,
-    interpret: bool = True,
-    escale: Optional[jax.Array] = None,
-    pbias: Optional[jax.Array] = None,
-    act: Optional[str] = None,
-    by_bits: Optional[int] = None,
-) -> jax.Array:
-    """BP/BS MVM on integer-grid operands: [..., N] x [N, M] -> [..., M].
-    ``escale``/``pbias``/``act``/``by_bits`` arm the fused datapath
-    epilogue (see :func:`cima_mvm_planes`)."""
-    x2, lead = prepare_inputs(x_q, cfg)
-    ws, fs = prepare_weights(w_q, cfg)
-    y = cima_mvm_planes(x2, ws, fs, cfg, block_b, block_m, interpret,
-                        escale, pbias, act, by_bits)
-    with jax.named_scope("cima.post"):
-        return y.reshape(*lead, w_q.shape[1])
-
-
-def cima_mvm_from_planes(
-    x_q: jax.Array,
-    ws: jax.Array,                # [N, BA, M] int8 weight bit planes
-    cfg: BpbsConfig,
-    block_b: int = 128,
-    block_m: int = 256,
-    interpret: bool = True,
-    escale: Optional[jax.Array] = None,
-    pbias: Optional[jax.Array] = None,
-    act: Optional[str] = None,
-    by_bits: Optional[int] = None,
-) -> jax.Array:
-    """BP/BS MVM consuming a pre-compiled weight image: the weight-
-    stationary serving path.  Only the (dynamic) inputs are decomposed
-    per call, inside the kernel; the weight planes come straight from
-    the loaded program."""
-    x2, lead = prepare_inputs(x_q, cfg)
-    fs = bank_full_scales(ws.shape[0], cfg)
-    y = cima_mvm_planes(x2, ws, fs, cfg, block_b, block_m, interpret,
-                        escale, pbias, act, by_bits)
-    with jax.named_scope("cima.post"):
-        return y.reshape(*lead, ws.shape[2])

@@ -118,8 +118,13 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
+    """Online-softmax attention, [B, H, Sq, D] -> [B, H, Sq, D].
+    ``interpret=None`` compiles the kernel on a TPU and interprets it
+    elsewhere."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     b, h, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     assert h % hkv == 0, "GQA requires heads % kv_heads == 0"

@@ -23,7 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.bpbs import BpbsConfig
-from repro.kernels.cima_mvm import cima_mvm_from_planes
+from repro.kernels.cima_mvm import cima_mvm
 
 CFG = BpbsConfig(ba=4, bx=4)          # skip_zero_planes on (the default)
 DECODE_ROWS = 16
@@ -72,18 +72,18 @@ def _compile(one_chip, cfg, rows, n, m, fused):
         args.append(arg((rows, m), jnp.float32))
 
         def fn(x, ws, es):
-            return cima_mvm_from_planes(x, ws, cfg, interpret=False,
-                                        escale=es, act="silu", by_bits=32)
+            return cima_mvm(x, ws, cfg, interpret=False, escale=es,
+                            act="silu", by_bits=32)
     elif fused == "col_sign":
         # per-column escale (folded batch-norm), then the ABN's sign
         args.append(arg((m,), jnp.float32))
 
         def fn(x, ws, es):
-            return cima_mvm_from_planes(x, ws, cfg, interpret=False,
-                                        escale=es, act="sign", by_bits=2)
+            return cima_mvm(x, ws, cfg, interpret=False, escale=es,
+                            act="sign", by_bits=2)
     else:
         def fn(x, ws):
-            return cima_mvm_from_planes(x, ws, cfg, interpret=False)
+            return cima_mvm(x, ws, cfg, interpret=False)
 
     compiled = jax.jit(fn).lower(*args).compile()
     want = sum(a.size * a.dtype.itemsize for a in args)

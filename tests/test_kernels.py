@@ -6,10 +6,12 @@ import numpy as np
 import pytest
 from hyp_compat import given, settings, st
 
-from repro.core.bpbs import BpbsConfig
+from repro.core.bpbs import BpbsConfig, weight_planes
 from repro.core.datapath import postreduce
 from repro.core.quant import Coding, int_range
-from repro.kernels import ops, ref
+from repro.kernels import ref
+from repro.kernels.cima_mvm import cima_mvm
+from repro.kernels.flash_attention import flash_attention
 
 rng = np.random.default_rng(0)
 
@@ -29,6 +31,11 @@ def _ops(coding, ba, bx, n, m, batch, sparsity=0.3, seed=0):
     if not (coding == Coding.XNOR and bx == 1):
         x = x * (r.random((batch, n)) > sparsity)
     return jnp.asarray(x, jnp.float32), jnp.asarray(w, jnp.float32)
+
+
+def _planes(w, cfg):
+    """The kernel's weight operand: the int8 planes of the layout function."""
+    return weight_planes(w, cfg).astype(jnp.int8)
 
 
 # tier-1 keeps one XNOR and one AND case of the shape sweep (the rest is
@@ -96,7 +103,7 @@ def test_cima_mvm_matches_oracle(coding, ba, bx, n, m, bank_n, bb, bm,
                    act="sign" if bx == 1 else "relu", by_bits=8)
         y_r = postreduce(y_r, scale=epi["escale"], bias=epi["pbias"],
                          act=epi["act"], by_bits=epi["by_bits"])
-    y_k = ops.cima_mvm(x, w, cfg, block_b=bb, block_m=bm, **epi)
+    y_k = cima_mvm(x, _planes(w, cfg), cfg, block_b=bb, block_m=bm, **epi)
     np.testing.assert_array_equal(np.asarray(y_k), np.asarray(y_r))
 
 
@@ -105,7 +112,7 @@ def test_cima_mvm_matches_oracle(coding, ba, bx, n, m, bank_n, bb, bm,
 def test_cima_mvm_adaptive_range(adaptive):
     x, w = _ops(Coding.XNOR, 4, 4, 600, 16, batch=4, sparsity=0.6)
     cfg = BpbsConfig(ba=4, bx=4, bank_n=512, adaptive_range=adaptive)
-    y_k = ops.cima_mvm(x, w, cfg, block_b=4, block_m=16)
+    y_k = cima_mvm(x, _planes(w, cfg), cfg, block_b=4, block_m=16)
     y_r = ref.cima_mvm_ref(x, w, cfg)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r), atol=1e-3)
 
@@ -113,7 +120,7 @@ def test_cima_mvm_adaptive_range(adaptive):
 def test_cima_mvm_ideal_adc_is_exact_gemm():
     x, w = _ops(Coding.XNOR, 4, 4, 2400, 16, batch=4)
     cfg = BpbsConfig(ba=4, bx=4, ideal_adc=True)
-    y_k = ops.cima_mvm(x, w, cfg, block_b=4, block_m=16)
+    y_k = cima_mvm(x, _planes(w, cfg), cfg, block_b=4, block_m=16)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(x @ w), atol=1e-3)
 
 
@@ -122,7 +129,7 @@ def test_cima_mvm_leading_batch_dims():
     x, w = _ops(Coding.XNOR, 2, 2, 128, 8, batch=6)
     x = x.reshape(2, 3, 128)
     cfg = BpbsConfig(ba=2, bx=2)
-    y = ops.cima_mvm(x, w, cfg, block_b=4, block_m=8)
+    y = cima_mvm(x, _planes(w, cfg), cfg, block_b=4, block_m=8)
     assert y.shape == (2, 3, 8)
     y_r = ref.cima_mvm_ref(x, w, cfg)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_r), atol=1e-3)
@@ -135,7 +142,7 @@ def test_cima_mvm_leading_batch_dims():
 def test_cima_mvm_property(seed, ba, bx, n, m):
     x, w = _ops(Coding.XNOR, ba, bx, n, m, batch=3, seed=seed)
     cfg = BpbsConfig(ba=ba, bx=bx)
-    y_k = ops.cima_mvm(x, w, cfg, block_b=4, block_m=8)
+    y_k = cima_mvm(x, _planes(w, cfg), cfg, block_b=4, block_m=8)
     y_r = ref.cima_mvm_ref(x, w, cfg)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r), atol=1e-3)
 
@@ -158,8 +165,8 @@ def test_flash_attention_matches_oracle(b, h, hkv, s, d, causal, window,
     q = jnp.asarray(r.normal(size=(b, h, s, d)), dtype)
     k = jnp.asarray(r.normal(size=(b, hkv, s, d)), dtype)
     v = jnp.asarray(r.normal(size=(b, hkv, s, d)), dtype)
-    o_k = ops.flash_attention(q, k, v, causal=causal, window=window,
-                              block_q=bq, block_k=bk)
+    o_k = flash_attention(q, k, v, causal=causal, window=window,
+                          block_q=bq, block_k=bk)
     o_r = ref.attention_ref(q, k, v, causal=causal, window=window)
     atol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(o_k, np.float32),
@@ -172,8 +179,8 @@ def test_flash_attention_matches_oracle_long_window():
     q = jnp.asarray(r.normal(size=(1, 2, 128, 64)), jnp.float32)
     k = jnp.asarray(r.normal(size=(1, 2, 128, 64)), jnp.float32)
     v = jnp.asarray(r.normal(size=(1, 2, 128, 64)), jnp.float32)
-    o_w = ops.flash_attention(q, k, v, causal=True, window=4096,
-                              block_q=64, block_k=64)
-    o_c = ops.flash_attention(q, k, v, causal=True, window=None,
-                              block_q=64, block_k=64)
+    o_w = flash_attention(q, k, v, causal=True, window=4096,
+                          block_q=64, block_k=64)
+    o_c = flash_attention(q, k, v, causal=True, window=None,
+                          block_q=64, block_k=64)
     np.testing.assert_allclose(np.asarray(o_w), np.asarray(o_c), atol=1e-5)

@@ -73,7 +73,7 @@ def test_compiled_cnn_step_carries_every_scope(backend, layers, want):
 
 
 @pytest.mark.parametrize("op_name,expected", [
-    ("jit(f)/cnn.layer3/jit(cima_mvm_planes)/cima.pad/jit(_pad)/pad",
+    ("jit(f)/cnn.layer3/jit(_cima_mvm_planes)/cima.pad/jit(_pad)/pad",
      ("cnn.layer3", "cima.pad")),
     ("jit(f)/cnn.layer0/cnn.im2col/concatenate",
      ("cnn.layer0", "cnn.im2col")),
@@ -101,7 +101,7 @@ ENTRY %main.9 (x.1: f32[8]) -> (f32[8], f32[8]) {
   %copy.7 = f32[8]{0:T(128)} copy(%x.1)
   %bitcast.2 = f32[8]{0} bitcast(%copy.7)
   %fusion.88 = f32[8]{0} fusion(%bitcast.2), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(f)/cnn.layer0/cima.planes/neg"}
-  %cima_bpbs_mvm.3 = f32[8]{0} custom-call(%fusion.88), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/cnn.layer0/jit(cima_mvm_planes)/cima.kernel/pallas_call"}
+  %cima_bpbs_mvm.3 = f32[8]{0} custom-call(%fusion.88), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/cnn.layer0/jit(_cima_mvm_planes)/cima.kernel/pallas_call"}
   %copy-start.4 = (f32[8]{0}, f32[8]{0}, u32[]) copy-start(%x.1)
   %copy-done.4 = f32[8]{0} copy-done(%copy-start.4)
   ROOT %tuple.5 = (f32[8]{0}, f32[8]{0}) tuple(%cima_bpbs_mvm.3, %copy-done.4)
